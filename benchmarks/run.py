@@ -47,6 +47,9 @@ def main(argv: list[str] | None = None) -> int:
     args = ap.parse_args(argv)
     if args.tiny:
         os.environ["BENCH_TINY"] = "1"
+    from repro.runtime.aot import setup_compilation_cache
+
+    setup_compilation_cache()
     mods = args.modules or DEFAULT_MODULES
     os.makedirs(args.out_dir, exist_ok=True)
 

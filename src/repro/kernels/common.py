@@ -13,9 +13,10 @@ import jax.numpy as jnp
 
 
 def use_interpret() -> bool:
-    """True when no TPU is available (CI / CPU container): run kernels in
-    Pallas interpret mode.  On a real TPU fleet this returns False and the
-    Mosaic-compiled kernel runs."""
+    """True when the default backend is not a TPU — the CPU test path
+    (``JAX_PLATFORMS=cpu``): run kernels in Pallas interpret mode.  On a TPU
+    this returns False and the Mosaic-compiled kernel runs; ``chip_smoke.py``
+    checks that it did (``tpu_custom_call`` in the compiled step)."""
     return jax.default_backend() != "tpu"
 
 

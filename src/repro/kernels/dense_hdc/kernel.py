@@ -15,36 +15,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-CHUNK = 16
-
-
-def _unpack(words: jax.Array, dim: int) -> jax.Array:
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (*words.shape, 32), words.ndim)
-    bits = (words[..., None] >> shifts) & jnp.uint32(1)
-    return bits.reshape(*words.shape[:-1], words.shape[-1] * 32)[..., :dim]
-
 
 def _dense_kernel(item_ref, elec_ref, out_ref, *, window: int, channels: int,
                   dim: int):
     elec = elec_ref[...]                                         # (C, W)
-    n_chunks = window // CHUNK
+    w = dim // 32
+    bit = jax.lax.broadcasted_iota(jnp.uint32, (32, w), 0)
 
-    def chunk_body(k, tcounts):
-        hvs = item_ref[0, 0, pl.dslice(k * CHUNK, CHUNK)]         # (CHUNK, C, W)
-        bound = jnp.bitwise_xor(hvs, elec[None])
-        bits = _unpack(bound, dim).astype(jnp.int32)              # (CHUNK, C, D)
-        # dtype pinned: under JAX_ENABLE_X64 jnp.sum would promote the
-        # fori_loop carry to int64 and break the carry-type invariant
-        scounts = jnp.sum(bits, axis=1, dtype=jnp.int32)          # (CHUNK, D)
-        spat = (scounts * 2 > channels).astype(jnp.int32)         # majority
-        return tcounts + jnp.sum(spat, axis=0, dtype=jnp.int32)
+    def cycle(j, tcounts):
+        bound = jnp.bitwise_xor(item_ref[0, 0, j], elec)         # (C, W)
+        # per-bit channel counts, (bit, word) layout: row c of the bound
+        # HVs broadcast over 32 sublanes, one bit position each
+        scounts = jnp.zeros((32, w), jnp.int32)
+        for c in range(channels):
+            scounts = scounts + ((bound[c:c + 1] >> bit)
+                                 & jnp.uint32(1)).astype(jnp.int32)
+        return tcounts + (scounts * 2 > channels).astype(jnp.int32)  # majority
 
     tcounts = jax.lax.fori_loop(
-        0, n_chunks, chunk_body, jnp.zeros((dim,), jnp.int32))
-    bits = (tcounts * 2 > window).reshape(dim // 32, 32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, bits.shape, 1)
-    out_ref[0, 0, :] = jnp.sum(bits.astype(jnp.uint32) << shifts, axis=1,
-                               dtype=jnp.uint32)
+        0, window, cycle, jnp.zeros((32, w), jnp.int32))
+    keep = (tcounts * 2 > window).astype(jnp.uint32)
+    # disjoint bits: the int32 sum is the OR (Mosaic reduces no unsigned)
+    words = jnp.sum((keep << bit).astype(jnp.int32), axis=0, keepdims=True,
+                    dtype=jnp.int32)
+    out_ref[0, 0] = words.astype(jnp.uint32)
 
 
 def dense_encoder_pallas(item_hvs: jax.Array, elec: jax.Array, *, window: int,
@@ -60,7 +54,8 @@ def dense_encoder_pallas(item_hvs: jax.Array, elec: jax.Array, *, window: int,
             pl.BlockSpec((1, 1, window, c, words), lambda i, j: (i, j, 0, 0, 0)),
             pl.BlockSpec((c, words), lambda i, j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, words), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, f, words), jnp.uint32),
+        # (B, F, 1, W): a block's last two dims must span the array's
+        out_specs=pl.BlockSpec((1, 1, 1, words), lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, f, 1, words), jnp.uint32),
         interpret=interpret,
-    )(item_hvs, elec)
+    )(item_hvs, elec)[:, :, 0]

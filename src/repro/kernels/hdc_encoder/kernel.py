@@ -2,21 +2,21 @@
 
 One grid step produces ONE time-frame HV for one (batch, frame) cell:
 
-    bound positions (window, C, S)  --bind-->  (pos + elec) mod L
-        --demux-->  per-cycle spatial one-hot  --OR/thin-->  (S, L) indicator
-        --temporal accumulate-->  (S, L) int32 counts
-        --threshold + pack-->  (D // 32,) uint32 frame HV
+    bound positions (window, S, C)  --bind-->  (pos + elec) mod L
+        --demux-->  per-cycle (S, L) one-hot per channel  --OR/thin-->
+        (S, L) spatial indicator  --temporal accumulate-->  (S, L) int32
+        --threshold-->  (S, L) frame bits (packed to D // 32 words outside)
 
 Fusing the whole encoder keeps the per-cycle 1024-bit spatial HVs and the
-8-bit temporal counters in VMEM: HBM traffic is just 56-bit positions in and
-one packed HV out per frame (the TPU analogue of the CompIM energy win; see
-README.md "Kernel & datapath design").
+8-bit temporal counters in VMEM: HBM traffic is the cycle's positions in and
+one frame's bits out (the TPU analogue of the CompIM energy win; see
+README.md "Kernel & datapath design").  The (S, L) planes are one (8, 128)
+vreg at the paper geometry: segments on sublanes, positions on lanes, so
+each channel's demux is a lane broadcast and one compare.
 
-VMEM budget per grid step (defaults window=256, C=64, S=8, L=128):
-  positions block  256*64*8  B   = 128 KiB
-  chunk one-hot    32*64*8*128 B =   2 MiB (int8, transient)
-  counters         8*128*4   B   =   4 KiB
-comfortably under the ~16 MiB/core VMEM of TPU v5e.
+VMEM per grid step (defaults window=256, C=64, S=8, L=128):
+  positions block  256*8*64*4 B = 512 KiB (int32)
+  counters / bits  8*128*4 B    =   4 KiB
 """
 
 from __future__ import annotations
@@ -27,37 +27,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-CHUNK = 32  # cycles expanded to one-hot at a time (VMEM working-set control)
+from repro.core import hv
 
 
 def _encoder_kernel(pos_ref, elec_ref, out_ref, *, window: int, segments: int,
                     seg_len: int, temporal_threshold: int,
                     spatial_thinning: bool, spatial_threshold: int):
-    c = elec_ref.shape[0]
-    elec = elec_ref[...].astype(jnp.int32)                       # (C, S)
-    n_chunks = window // CHUNK
+    c = elec_ref.shape[1]
+    elec = elec_ref[...]                                          # (S, C)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (segments, seg_len), 1)
 
-    def chunk_body(k, counts):
-        p = pos_ref[0, 0, pl.dslice(k * CHUNK, CHUNK)]            # (CHUNK, C, S)
-        bound = (p.astype(jnp.int32) + elec[None]) % seg_len      # (CHUNK, C, S)
-        iota = jax.lax.broadcasted_iota(
-            jnp.int32, (CHUNK, c, segments, seg_len), 3)
-        onehot = (bound[..., None] == iota)                       # (CHUNK, C, S, L)
+    def cycle(j, counts):
+        bound = pos_ref[0, 0, j] + elec                           # (S, C)
+        bound = jnp.where(bound >= seg_len, bound - seg_len, bound)
+        spat = jnp.zeros((segments, seg_len), jnp.int32)
+        for ch in range(c):
+            hit = (bound[:, ch:ch + 1] == lane).astype(jnp.int32)  # (S, L)
+            spat = spat + hit if spatial_thinning else spat | hit
         if spatial_thinning:
-            spat = (jnp.sum(onehot.astype(jnp.int32), axis=1, dtype=jnp.int32)
-                    >= spatial_threshold)
-        else:
-            spat = jnp.any(onehot, axis=1)                        # (CHUNK, S, L)
-        # dtype pinned: under JAX_ENABLE_X64 jnp.sum would promote the
-        # fori_loop carry to int64 and break the carry-type invariant
-        return counts + jnp.sum(spat.astype(jnp.int32), axis=0, dtype=jnp.int32)
+            spat = (spat >= spatial_threshold).astype(jnp.int32)
+        return counts + spat
 
     counts = jax.lax.fori_loop(
-        0, n_chunks, chunk_body, jnp.zeros((segments, seg_len), jnp.int32))
-    bits = (counts >= temporal_threshold).reshape(segments * seg_len // 32, 32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, bits.shape, 1)
-    words = jnp.sum(bits.astype(jnp.uint32) << shifts, axis=1, dtype=jnp.uint32)
-    out_ref[0, 0, :] = words
+        0, window, cycle, jnp.zeros((segments, seg_len), jnp.int32))
+    out_ref[0, 0] = (counts >= temporal_threshold).astype(jnp.int32)
 
 
 def encoder_pallas(positions: jax.Array, elec: jax.Array, *, window: int,
@@ -74,14 +67,17 @@ def encoder_pallas(positions: jax.Array, elec: jax.Array, *, window: int,
         _encoder_kernel, window=window, segments=segments, seg_len=seg_len,
         temporal_threshold=temporal_threshold,
         spatial_thinning=spatial_thinning, spatial_threshold=spatial_threshold)
-    return pl.pallas_call(
+    # segments on sublanes, channels on lanes; positions widened to int32
+    pos = positions.astype(jnp.int32).swapaxes(-1, -2)          # (B,F,w,S,C)
+    bits = pl.pallas_call(
         kernel,
         grid=(b, f),
         in_specs=[
-            pl.BlockSpec((1, 1, window, c, s), lambda i, j: (i, j, 0, 0, 0)),
-            pl.BlockSpec((c, s), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, 1, window, s, c), lambda i, j: (i, j, 0, 0, 0)),
+            pl.BlockSpec((s, c), lambda i, j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, dim // 32), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, f, dim // 32), jnp.uint32),
+        out_specs=pl.BlockSpec((1, 1, s, seg_len), lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, f, s, seg_len), jnp.int32),
         interpret=interpret,
-    )(positions, elec)
+    )(pos, elec.astype(jnp.int32).T)
+    return hv.pack_bits(bits.reshape(b, f, dim))

@@ -1,46 +1,45 @@
 """Fused code-domain fleet-step kernel: gather + bind + bundle + counters.
 
 One grid cell is (session, 32-cycle time group).  The kernel consumes RAW
-uint8 LBP codes — the only per-cycle input that ever crosses HBM — and keeps
-the session's pre-bound CompIM table bank (binding folded into the table
-build, serve/dispatch.py) resident in VMEM, selected per session by a
+LBP codes — the only per-cycle input that ever crosses HBM — and keeps the
+session's pre-bound CompIM table bank (binding folded into the table build,
+serve/dispatch.py) resident in VMEM, selected per session by a
 scalar-prefetched owner index (the table BlockSpec's index map reads
 ``owner[i]``, so patients sharing a codebook share one VMEM block and no
 per-session table copy is ever materialized):
 
-    codes (32, C) uint8
-        --VMEM table gather-->  (32, C, W) bound rows
-           (rows[j, c] = table[c, codes[j, c]]; the CompIM insight one
-           stage further: binding IS the lookup)
-        --spatial bundle-->     (32, W) per-cycle packed HVs
-           (OR tree / adder tree + thinning / majority, per variant)
-        --bit transpose-->      (32, W) time-packed bit planes
-           (one uint32 = 32 cycles of one bit position)
-        --masked popcount-->    (K+1, 32, W) int32 counter bank
+    codes (32, C) in SMEM (four 8-bit codes per int32 word)
+        --VMEM row loads-->     per cycle, C bound rows (1, W)
+           (row c = table[c, codes[j, c]], a dynamic sublane slice of the
+           resident bank; the CompIM insight one stage further: binding IS
+           the lookup)
+        --spatial bundle-->     per cycle, (32, W) kept bits (bit b of word w)
+           (OR of the rows / per-bit channel count + thinning / majority)
+        --slot counters-->      (K+1, 32, W) int32 counter bank
            accumulated across time groups, like hdc_encoder's counter bank
 
-HBM traffic per group is 32*C bytes of codes in and (on the last group) one
-(K+1, D) count bank out — the bound rows, the per-cycle HVs, the bit planes
-and the temporal counters never leave VMEM, and no float math or unpacked
-expansion exists anywhere (the TPU analogue of the paper's binary-domain
-argument; see README.md "Kernel & datapath design").  The old bound-rows
-kernel shipped (32, C, W) uint32 per group from HBM — 128 bytes per
-(cycle, channel) where this kernel ships ONE.
+The codes, the emission schedule and the channel mask are read as scalars
+from SMEM; the table bank and the counters live in VMEM.  HBM traffic per
+group is 32*C bytes of codes in and (on the last group) one (K+1, D) count
+bank out — the bound rows, the per-cycle HVs and the temporal counters never
+leave the core, and no float math exists anywhere (the TPU analogue of the
+paper's binary-domain argument; see README.md "Kernel & datapath design").
 
-VMEM per grid step (defaults window=256, C=64, K=64 codes, D=1024, K+1=2):
-  table bank    64*64*32*4 B = 1 MiB  (resident; re-fetched only when the
-                                       session's owner row changes)
-  codes block      32*64 B   =   2 KiB
-  spatial/planes  32*32*4 B  =   4 KiB
-  counter bank  2*32*32*4 B  =   8 KiB
+Memory per grid step (defaults window=256, C=64, K=64 codes, D=1024, K+1=2):
+  table bank    64*64*32*4 B = 512 KiB (VMEM, lane-padded to 2 MiB;
+                                        re-fetched only when the session's
+                                        owner row changes)
+  codes block   32*16*4 B    =   2 KiB (SMEM)
+  counter bank  2*32*32*4 B  =   8 KiB (VMEM)
 
 The emission schedule arrives as time-packed per-slot cycle masks
 (ref.emission_masks) computed on device from (filled, lengths): bit j of
-mask word g selects cycle 32 g + j into a slot, so the masked popcount IS
-the temporal bundling of that slot.  Bit-exact with the pure-jnp code-domain
-path (dispatch.owner_spatial_codes + ref.fleet_counts_ref); validated in
-interpret mode (tests/test_kernels.py) — Mosaic lowering of the in-kernel
-gather is untested on real TPUs, like the SWAR transpose (ROADMAP).
+mask word g selects cycle 32 g + j into a slot, so adding a cycle's kept
+bits to every slot whose mask bit is set IS the temporal bundling.
+Bit-exact with the pure-jnp code-domain path (dispatch.owner_spatial_codes
++ ref.fleet_counts_ref); validated in interpret mode (tests/test_kernels.py)
+and compiled by Mosaic for a TPU v5e at paper geometry in every bundle mode,
+masked and unmasked (tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations
@@ -52,83 +51,73 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import hv
-
-
-def _spatial_bundle(bound: jax.Array, *, mode: str, channels: int, dim: int,
-                    threshold: int, live=None) -> jax.Array:
-    """(32, C, W) bound rows -> (32, W) per-cycle packed spatial HVs.
-
-    Mirrors dispatch.owner_spatial_codes: ``or`` = OR tree (optimized
-    sparse), ``thin`` = adder tree + threshold (naive sparse), ``majority``
-    = adder tree + majority (dense).
-
-    ``live`` (traced int32 scalar) is the channel-masked path's live
-    channel count: the caller has already zeroed quarantined rows (OR
-    identity / zero addend), so this only renormalizes the count-variant
-    denominators — thinning threshold via the ceil rule of
-    dispatch.effective_spatial_threshold, majority over the live count.
-    """
-    if mode == "or":
-        x = bound
-        n = x.shape[1]
-        while n > 1:  # pairwise OR tree, fully packed
-            half = n // 2
-            merged = x[:, :half] | x[:, half:2 * half]
-            if n % 2:
-                merged = jnp.concatenate([merged, x[:, 2 * half:]], axis=1)
-            x = merged
-            n = x.shape[1]
-        return x[:, 0]
-    # count variants need per-bit channel sums
-    w = dim // 32
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (32, channels, w, 32), 3)
-    bits = (bound[..., None] >> shifts) & jnp.uint32(1)       # (32, C, w, 32)
-    counts = jnp.sum(bits.astype(jnp.int32), axis=1, dtype=jnp.int32)
-    if mode == "thin":
-        if live is None:
-            keep = counts >= threshold
-        else:
-            thr = jnp.maximum(1, (threshold * live + channels - 1) // channels)
-            keep = counts >= thr
-    else:  # majority (ties broken low, matches hv.majority_pack)
-        keep = counts * 2 > (channels if live is None else live)
-    pack_shifts = jax.lax.broadcasted_iota(jnp.uint32, (32, w, 32), 2)
-    return jnp.sum(keep.astype(jnp.uint32) << pack_shifts, axis=2,
-                   dtype=jnp.uint32)
-
 
 def _fleet_kernel(owner_ref, tab_ref, codes_ref, tm_ref, *refs,
                   mode: str, channels: int, n_codes: int, dim: int,
                   threshold: int, masked: bool):
+    """Spatial-bundle modes mirror dispatch.owner_spatial_codes: ``or`` =
+    OR of the bound rows (optimized sparse), ``thin`` = per-bit channel
+    count + threshold (naive sparse), ``majority`` = per-bit count +
+    majority (dense).
+
+    With ``masked`` a quarantined channel's row contributes nothing (OR
+    identity / zero addend) and the count-variant denominators renormalize
+    to the live channel count — thinning threshold via the ceil rule of
+    dispatch.effective_spatial_threshold, majority over the live count."""
     del owner_ref  # consumed by the BlockSpec index maps (scalar prefetch)
     cm_ref, out_ref = (refs if masked else (None,) + refs)
     g = pl.program_id(1)
+    kp1 = out_ref.shape[1]
+    w = dim // 32
 
     @pl.when(g == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tab = tab_ref[0]                                       # (C, K, W)
-    # out-of-alphabet codes clamp within their channel's rows, like the
-    # jnp path (dispatch.owner_spatial_codes) and the reference indexing
-    cb = jnp.minimum(codes_ref[0].astype(jnp.int32), n_codes - 1)  # (32, C)
-    # per-cycle gather out of the VMEM-resident bank: row (c, codes[j, c])
-    flat = tab.reshape(channels * n_codes, tab.shape[-1])
-    cbase = jax.lax.broadcasted_iota(jnp.int32, (32, channels), 1) * n_codes
-    bound = jnp.take(flat, cbase + cb, axis=0)             # (32, C, W)
-    live = None
+    live = channels
     if masked:
-        cm = cm_ref[0]                                     # (C,) uint32
-        bound = bound * cm[None, :, None]  # quarantined rows contribute 0
-        live = jnp.sum(cm.astype(jnp.int32), dtype=jnp.int32)
-    words = _spatial_bundle(bound, mode=mode, channels=channels, dim=dim,
-                            threshold=threshold, live=live)  # (32, W)
-    planes = hv.bit_transpose32(words)                     # (32b, W)
-    tm = tm_ref[0, :, 0]                                   # (K+1,) uint32
-    # masked popcount: one AND + popcount bundles 32 cycles into each slot
-    contrib = hv.lax_popcount(planes[None] & tm[:, None, None])
-    out_ref[0] += contrib.astype(jnp.int32)                # (1, K+1, 32, W)
+        live = cm_ref[0, 0, 0]
+        for c in range(1, channels):
+            live = live + cm_ref[0, 0, c]
+    if mode == "thin" and masked:
+        threshold = jnp.maximum(1, (threshold * live + channels - 1)
+                                // channels)
+    bit = jax.lax.broadcasted_iota(jnp.uint32, (32, w), 0)
+
+    def bound_row(j, c):
+        word = codes_ref[0, j, c // 4]
+        # out-of-alphabet codes clamp within their channel's rows, like the
+        # jnp path (dispatch.owner_spatial_codes) and the reference indexing
+        code = jnp.minimum((word >> (8 * (c % 4))) & 0xFF, n_codes - 1)
+        row = tab_ref[0, c, pl.ds(code, 1), :]              # (1, W)
+        if masked:
+            row = jnp.where(cm_ref[0, 0, c] != 0, row, jnp.uint32(0))
+        return row
+
+    def cycle(j, acc):
+        if mode == "or":
+            word = bound_row(j, 0)
+            for c in range(1, channels):
+                word = word | bound_row(j, c)
+            keep = (word >> bit) & jnp.uint32(1)            # (32, W)
+        else:
+            cnt = jnp.zeros((32, w), jnp.uint32)
+            for c in range(channels):
+                cnt = cnt + ((bound_row(j, c) >> bit) & jnp.uint32(1))
+            cnt = cnt.astype(jnp.int32)
+            if mode == "thin":
+                keep = cnt >= threshold
+            else:  # majority (ties broken low, matches hv.majority_pack)
+                keep = cnt * 2 > live
+        keep = keep.astype(jnp.int32)
+        # cycle j joins every slot whose time-packed mask has bit j set
+        return tuple(a + keep * ((tm_ref[0, k, g] >> j) & 1)
+                     for k, a in enumerate(acc))
+
+    zero = jnp.zeros((32, w), jnp.int32)
+    acc = jax.lax.fori_loop(0, 32, cycle, (zero,) * kp1)
+    for k in range(kp1):
+        out_ref[0, k] += acc[k]
 
 
 def fleet_counts_pallas(tables: jax.Array, owner: jax.Array,
@@ -144,9 +133,9 @@ def fleet_counts_pallas(tables: jax.Array, owner: jax.Array,
     tm: (S, K+1, T32 // 32) uint32 time-packed slot masks
     (ref.emission_masks);
     chan_mask: optional (S, C) uint32 per-session channel mask (1 = live)
-    — a fourth VMEM operand, one (1, C) row per session: quarantined
-    channels drop out of the spatial bundle and the count-variant
-    denominators renormalize to the live count (see _spatial_bundle).
+    — a fourth operand, one SMEM row per session: quarantined channels
+    drop out of the spatial bundle and the count-variant denominators
+    renormalize to the live count (see _fleet_kernel).
     Returns (S, K+1, D) int32 slot counts."""
     p, c, k, w = tables.shape
     s, t32, c2 = codes.shape
@@ -154,18 +143,31 @@ def fleet_counts_pallas(tables: jax.Array, owner: jax.Array,
     groups = t32 // 32
     kp1 = tm.shape[1]
     masked = chan_mask is not None
+    # four codes per int32 word, little end first: SMEM holds 32-bit scalars
+    cw = -(-c // 4)
+    packed = jnp.pad(codes, ((0, 0), (0, 0), (0, cw * 4 - c))).astype(
+        jnp.int32).reshape(s, t32, cw, 4)
+    packed = (packed[..., 0] | (packed[..., 1] << 8) | (packed[..., 2] << 16)
+              | (packed[..., 3] << 24))
     kernel = functools.partial(_fleet_kernel, mode=mode, channels=c,
                                n_codes=k, dim=dim, threshold=threshold,
                                masked=masked)
+    smem = pltpu.SMEM
     in_specs = [
         pl.BlockSpec((1, c, k, w), lambda i, g, owner_ref: (owner_ref[i], 0, 0, 0)),
-        pl.BlockSpec((1, 32, c), lambda i, g, owner_ref: (i, g, 0)),
-        pl.BlockSpec((1, kp1, 1), lambda i, g, owner_ref: (i, 0, g)),
+        pl.BlockSpec((1, 32, cw), lambda i, g, owner_ref: (i, g, 0),
+                     memory_space=smem),
+        pl.BlockSpec((1, kp1, groups), lambda i, g, owner_ref: (i, 0, 0),
+                     memory_space=smem),
     ]
-    inputs = [owner.astype(jnp.int32), tables, codes, tm]
+    inputs = [owner.astype(jnp.int32), tables, packed,
+              jax.lax.bitcast_convert_type(tm, jnp.int32)]
     if masked:
-        in_specs.append(pl.BlockSpec((1, c), lambda i, g, owner_ref: (i, 0)))
-        inputs.append(chan_mask.astype(jnp.uint32))
+        # (S, 1, C): a block's last two dims must span the array's
+        in_specs.append(pl.BlockSpec((1, 1, c),
+                                     lambda i, g, owner_ref: (i, 0, 0),
+                                     memory_space=smem))
+        inputs.append(chan_mask.astype(jnp.int32).reshape(s, 1, c))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(s, groups),
@@ -179,5 +181,5 @@ def fleet_counts_pallas(tables: jax.Array, owner: jax.Array,
         out_shape=jax.ShapeDtypeStruct((s, kp1, 32, w), jnp.int32),
         interpret=interpret,
     )(*inputs)
-    # time_pack's (bit, word) layout -> standard d = word * 32 + bit order
+    # (bit, word) layout -> standard d = word * 32 + bit order
     return counts.transpose(0, 1, 3, 2).reshape(s, kp1, dim)

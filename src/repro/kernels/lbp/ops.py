@@ -11,8 +11,9 @@ from repro.kernels.common import use_interpret
 from repro.kernels.lbp.kernel import lbp_pallas
 from repro.kernels.lbp.ref import lbp_ref
 
-# keep one (chunk+bits, C) f32 tile ~<= 4 MiB for C = 64
-MAX_CHUNK_T = 16384
+# one (chunk+bits, C) f32 tile, lane-padded to 128 and double-buffered,
+# stays well inside the 16 MiB scoped VMEM of a v5e (2 MiB at C <= 128)
+MAX_CHUNK_T = 4096
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "use_kernel"))

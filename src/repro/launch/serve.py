@@ -56,7 +56,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.launch.train import parse_mesh
+from repro.launch.mesh import parse_mesh
 
 
 class _GracefulStop:
@@ -94,16 +94,14 @@ class _GracefulStop:
         return signal.Signals(self.signum).name if self.signum else ""
 
 
-def _build_hdc_fleet(args):
-    """Train a small synthetic per-patient bank and assemble the fleet."""
+def train_bank(cfg, patients: int, rng) -> dict:
+    """Train a synthetic per-patient bank: ``patient<p>`` -> pipeline, each
+    calibrated to its own density operating point (the programmed temporal
+    threshold register) and trained one-shot on random codes from ``rng``
+    (a ``numpy.random.Generator``)."""
     import numpy as np
 
-    from repro.core.pipeline import HDCConfig, HDCPipeline
-    from repro.serve.fleet import StreamingFleet
-
-    mesh = parse_mesh(args.mesh)
-    cfg = HDCConfig(variant=args.variant)
-    rng = np.random.default_rng(0)
+    from repro.core.pipeline import HDCPipeline
 
     def trained(seed: int) -> HDCPipeline:
         codes = jnp.asarray(
@@ -115,8 +113,22 @@ def _build_hdc_fleet(args):
         pipe = pipe.calibrate_density(codes, target=0.2 + 0.05 * (seed % 4))
         return pipe.train_one_shot(codes, jnp.asarray(labels))
 
+    return {f"patient{p}": trained(p) for p in range(patients)}
+
+
+def _build_hdc_fleet(args):
+    """Train a small synthetic per-patient bank and assemble the fleet."""
+    import numpy as np
+
+    from repro.core.pipeline import HDCConfig
+    from repro.serve.fleet import StreamingFleet
+
+    mesh = parse_mesh(args.mesh)
+    cfg = HDCConfig(variant=args.variant)
+    rng = np.random.default_rng(0)
+
     t0 = time.perf_counter()
-    bank = {f"patient{p}": trained(p) for p in range(args.patients)}
+    bank = train_bank(cfg, args.patients, rng)
     owners = [f"patient{i % args.patients}" for i in range(args.sessions)]
     fleet = StreamingFleet(bank, owners, mesh=mesh,
                            channel_masking=args.channel_health)
@@ -370,6 +382,9 @@ def main():
                          " (runtime/aot.py): `compile` writes it, `serve` "
                          "warms the fleet from it")
     args = ap.parse_args()
+    from repro.runtime.aot import setup_compilation_cache
+
+    setup_compilation_cache()
     if args.command == "compile":
         run_hdc_compile(args)
         return

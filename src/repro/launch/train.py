@@ -31,18 +31,11 @@ import jax.numpy as jnp
 from repro.ckpt import checkpoint as ckpt
 from repro.configs.registry import get_config
 from repro.data import lm as lmdata
+from repro.launch.mesh import parse_mesh
 from repro.models import params as pmod
 from repro.optim import adamw, compress
 from repro.runtime import steps as steps_mod
 from repro.runtime.sharding import tree_shardings
-
-
-def parse_mesh(s: str | None):
-    if not s or s == "none":
-        return None
-    dims = tuple(int(x) for x in s.split("x"))
-    axes = {1: ("data",), 2: ("data", "model"), 3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, axes)
 
 
 def train_loop(args) -> dict:

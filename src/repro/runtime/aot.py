@@ -19,19 +19,11 @@ mechanisms, both exercised by ``benchmarks/bench_coldstart.py``:
   portable middle tier: when the executable is absent, signature-mismatched
   or unloadable on this backend, the exported program is deserialized and
   recompiled — tracing is still skipped, XLA compile is paid once.
-* **Persistent compilation cache.**  ``save_artifact`` also pre-COMPILES
-  every entry with JAX's persistent compilation cache pointed into the
-  artifact (``<dir>/xla_cache``), so the XLA executables themselves ship
-  with it.  The cache serves plain-JIT restarts: point
-  ``compilation_cache(<dir>/xla_cache)`` (or ``load_artifact(...,
-  enable_cache=True)``) at it and a re-trace's compile becomes a disk hit
-  instead of an XLA compile.  CI persists the same directory across
-  Cache use is opt-in and must stay scoped: jaxlib 0.4.3x's persistent
-  cache corrupts the heap (glibc abort / segfault) when enabled around the
-  donated fleet-step program — on cache WRITES as well as hits — so
-  nothing in this module leaves the cache enabled implicitly, and CI jobs
-  set no ``JAX_COMPILATION_CACHE_DIR`` (the executable tiers above are
-  unaffected: they never touch the cache).
+* **Persistent compilation cache.**  ``save_artifact`` also compiles the
+  plain-jit form of every entry, so when JAX's persistent compilation cache
+  is on (``setup_compilation_cache``, called by the entry points) a
+  plain-JIT restart that shares the cache directory finds its executables
+  there instead of compiling them.
 
 Artifacts are **versioned**: the manifest records ``artifact_key()`` — the
 jax version, the device kind, and a hash of the kernel/serving sources
@@ -47,16 +39,17 @@ Layout of one artifact directory::
     <dir>/manifest.json         {"version", "key", "entries": [...]}
     <dir>/entries/e_00000.xlaexec   pickled PjRt executable + arg/out trees
     <dir>/entries/e_00000.jaxexport serialized StableHLO (jax.export)
-    <dir>/xla_cache/...         persistent-compilation-cache files
 
-Everything degrades gracefully: a missing directory, an unreadable blob, a
-stale key, or a jax build without ``jax.export`` serialization all fall back
-to JIT — AOT is an optimization, never a correctness dependency (the AOT and
-JIT paths are bit-exact; tests/test_aot.py pins this per variant/backend).
+A missing directory, an unreadable blob or a stale key falls back to JIT
+with a warning — AOT is an optimization, never a correctness dependency (the
+AOT and JIT paths are bit-exact; tests/test_aot.py pins this per
+variant/backend).  Callers that must not fall back (``chip_smoke.py``) turn
+those warnings into errors and check ``warmup``'s counts.
 """
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import hashlib
 import json
@@ -69,26 +62,12 @@ from typing import Any, Callable, Sequence
 
 import jax
 import numpy as np
-
-try:  # jax.export with pytree serialization (>= 0.4.36); degrade without it
-    from jax import export as _jax_export
-
-    _HAVE_EXPORT = hasattr(_jax_export, "register_pytree_node_serialization")
-except Exception:  # pragma: no cover - import-level environment guard
-    _jax_export = None
-    _HAVE_EXPORT = False
-
-try:  # PjRt compiled-executable pickling; degrade to StableHLO + recompile
-    from jax.experimental import serialize_executable as _jax_se
-
-    _HAVE_EXEC = hasattr(_jax_se, "deserialize_and_load")
-except Exception:  # pragma: no cover - import-level environment guard
-    _jax_se = None
-    _HAVE_EXEC = False
+from jax import export as jax_export
+from jax.experimental import serialize_executable
+from jax.experimental.compilation_cache import compilation_cache as _cc
 
 MANIFEST = "manifest.json"
 ENTRY_DIR = "entries"
-XLA_CACHE_DIR = "xla_cache"
 ARTIFACT_VERSION = 1
 
 # the sources whose edits change the serving programs: the kernels, the
@@ -138,16 +117,13 @@ def artifact_key(*, device=None, root: str | None = None) -> dict:
     }
 
 
-def register_pytree_serialization(cls: type, name: str) -> bool:
+def register_pytree_serialization(cls: type, name: str) -> None:
     """Register a (meta-field-free) dataclass pytree for ``jax.export``
-    serialization; idempotent, False when export serialization is
-    unavailable.  Producers call this next to their
+    serialization; idempotent.  Producers call this next to their
     ``register_dataclass`` so their state types can cross the export
     boundary."""
-    if not _HAVE_EXPORT:
-        return False
     try:
-        _jax_export.register_pytree_node_serialization(
+        jax_export.register_pytree_node_serialization(
             cls,
             serialized_name=name,
             serialize_auxdata=lambda aux: b"",
@@ -155,7 +131,6 @@ def register_pytree_serialization(cls: type, name: str) -> bool:
         )
     except ValueError:  # already registered (idempotent re-import)
         pass
-    return True
 
 
 # ---------------------------------------------------------------------------
@@ -163,67 +138,40 @@ def register_pytree_serialization(cls: type, name: str) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _reset_cache_state() -> None:
-    # jax initializes the persistent cache AT MOST ONCE per process, at the
-    # first compile — a dir configured after that (the usual case here:
-    # training compiles run long before an artifact is saved/loaded) would
-    # silently never take effect.  reset_cache() returns the module to its
-    # uninitialized state so the next compile picks up the new dir.
-    try:
-        from jax.experimental.compilation_cache import compilation_cache as cc
+def setup_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.
 
-        cc.reset_cache()
-    except Exception:  # pragma: no cover - private-ish API moved/absent
-        pass
-
-
-def enable_compilation_cache(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path`` (created if
-    missing) with thresholds at zero, so every serving executable persists.
-    Process-global, like the cache itself."""
-    os.makedirs(path, exist_ok=True)
-    _reset_cache_state()
-    jax.config.update("jax_compilation_cache_dir", path)
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and nothing else is set here; otherwise the cache goes to the
+    fixed ``<checkout>/.jax_cache`` (gitignored) — a fixed path, because the
+    path is part of what a later process must find again.  Every executable
+    persists (no minimum compile time: the kernels compile in well under
+    the default second).  Called by the entry points (``launch/serve.py``,
+    ``benchmarks/run.py``, ``chip_smoke.py``), never at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.dirname(os.path.dirname(_repro_root())),
+                            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
+@contextlib.contextmanager
+def persistent_cache_off():
+    """Compile the enclosed block without JAX's persistent compilation
+    cache, wherever it lives, and restore the setting after.  JAX decides
+    once per process whether the cache is used, so that decision is reset on
+    the way in and out."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    _cc.reset_cache()
     try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # older jax: size threshold flag absent
-        pass
-
-
-def disable_compilation_cache() -> None:
-    _reset_cache_state()
-    jax.config.update("jax_compilation_cache_dir", None)
-
-
-def compilation_cache_dir() -> str | None:
-    return jax.config.jax_compilation_cache_dir
-
-
-class compilation_cache:
-    """Context manager: run a block under (or explicitly without) the
-    persistent compilation cache, restoring the previous setting after —
-    the cold-start benchmark uses this to measure a genuinely cache-free
-    fresh JIT inside a process whose CI environment has the cache on."""
-
-    def __init__(self, path: str | None):
-        self._path = path
-        self._prev: str | None = None
-
-    def __enter__(self):
-        self._prev = compilation_cache_dir()
-        if self._path is None:
-            disable_compilation_cache()
-        else:
-            enable_compilation_cache(self._path)
-        return self
-
-    def __exit__(self, *exc):
-        if self._prev is None:
-            disable_compilation_cache()
-        else:
-            enable_compilation_cache(self._prev)
-        return False
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        _cc.reset_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -239,7 +187,7 @@ class AOTEntry:
     jit needs at trace time; they are baked into the exported program, so
     loaders call the compiled entry with ``args`` only.
 
-    ``cache_args``, when set, is a second signature to pre-compile into the
+    ``cache_args``, when set, is a second signature to pre-compile for the
     persistent cache ONLY (not exported): device-PINNED avals hash to a
     different cache key than the portable ``args`` form, and a plain-JIT
     restart that merely shares the cache directory compiles the pinned form
@@ -273,15 +221,12 @@ def save_artifact(
     ``entries/e_<i>.jaxexport``, (2) pickles the compiled PjRt executable of
     the donation-free export-wrapped program to ``entries/e_<i>.xlaexec``
     (the tier the load path prefers: no tracing, no XLA compile), and
-    (3) compiles BOTH the export-wrapped and the plain-jit form of the
-    program with the persistent compilation cache pointed into the
-    artifact, for plain-JIT restarts that merely share the cache directory.
-    Returns the manifest dict.
+    (3) compiles the plain-jit form of the program too, which lands in the
+    persistent compilation cache when that is on, for plain-JIT restarts
+    that merely share the cache directory.  Returns the manifest dict.
 
-    Entries whose export fails (e.g. a jax build without export
-    serialization) are still cache-compiled and recorded with
-    ``"exported": false`` — the load path then JIT-compiles them against
-    the shipped cache, which is the graceful middle tier.
+    Entries whose export fails are still compiled and recorded with
+    ``"exported": false`` — the load path then JIT-compiles them.
     """
     os.makedirs(os.path.join(path, ENTRY_DIR), exist_ok=True)
     manifest: dict = {
@@ -290,53 +235,50 @@ def save_artifact(
         "entries": [],
     }
     names = set()
-    with compilation_cache(os.path.join(path, XLA_CACHE_DIR)):
-        for i, e in enumerate(entries):
-            if e.name in names:
-                raise ValueError(f"duplicate AOT entry name {e.name!r}")
-            names.add(e.name)
-            rec = {"name": e.name, "file": None, "exported": False}
-            t0 = time.perf_counter()
-            # plain-jit compile: populates the cache for workers that JIT
-            # with the shared cache dir but never load the blobs
-            e.fn.lower(*e.args, *e.static).compile()
-            if e.cache_args is not None:
-                e.fn.lower(*e.cache_args, *e.static).compile()
-            blob = None
-            if _HAVE_EXPORT:
-                try:
-                    blob = _jax_export.export(e.fn)(
-                        *e.args, *e.static).serialize()
-                except Exception as ex:  # unexportable program: cache-only
-                    warnings.warn(
-                        f"AOT entry {e.name!r}: export failed "
-                        f"({type(ex).__name__}: {ex}); shipping "
-                        "compilation-cache entry only",
-                        stacklevel=2,
-                    )
-            if blob is not None:
-                fname = f"e_{i:05d}.jaxexport"
-                with open(os.path.join(path, ENTRY_DIR, fname), "wb") as f:
-                    f.write(blob)
-                rec["file"] = fname
-                rec["exported"] = True
-                # the load path compiles the DESERIALIZED program, whose
-                # cache key differs from the plain jit's — pre-compile that
-                # form too so loads are pure cache hits
+    for i, e in enumerate(entries):
+        if e.name in names:
+            raise ValueError(f"duplicate AOT entry name {e.name!r}")
+        names.add(e.name)
+        rec = {"name": e.name, "file": None, "exported": False}
+        t0 = time.perf_counter()
+        # plain-jit compile: populates the persistent cache (when on) for
+        # workers that JIT with the shared cache dir but never load the blobs
+        e.fn.lower(*e.args, *e.static).compile()
+        if e.cache_args is not None:
+            e.fn.lower(*e.cache_args, *e.static).compile()
+        blob = None
+        try:
+            blob = jax_export.export(e.fn)(*e.args, *e.static).serialize()
+        except Exception as ex:  # unexportable program: JIT at load time
+            warnings.warn(
+                f"AOT entry {e.name!r}: export failed "
+                f"({type(ex).__name__}: {ex}); shipping no executable",
+                stacklevel=2,
+            )
+        if blob is not None:
+            fname = f"e_{i:05d}.jaxexport"
+            with open(os.path.join(path, ENTRY_DIR, fname), "wb") as f:
+                f.write(blob)
+            rec["file"] = fname
+            rec["exported"] = True
+            # compiled afresh: an executable served from the persistent
+            # cache re-serializes into a blob that loads but fails at run
+            # time (XLA:CPU: "Function ... not found")
+            with persistent_cache_off():
                 compiled = _compile_exported(
-                    _jax_export.deserialize(blob), e.args)
-                # ship the XLA executable itself (the load path then skips
-                # XLA entirely).  This is the donation-free export-wrapped
-                # form — exactly what the load path would have compiled
-                exec_blob = _serialize_executable(e.name, compiled)
-                if exec_blob is not None:
-                    xname = f"e_{i:05d}.xlaexec"
-                    with open(os.path.join(path, ENTRY_DIR, xname),
-                              "wb") as f:
-                        f.write(exec_blob)
-                    rec["executable"] = xname
-            rec["compile_s"] = round(time.perf_counter() - t0, 4)
-            manifest["entries"].append(rec)
+                    jax_export.deserialize(blob), e.args)
+            # ship the XLA executable itself (the load path then skips
+            # XLA entirely).  This is the donation-free export-wrapped
+            # form — exactly what the load path would have compiled
+            exec_blob = _serialize_executable(e.name, compiled)
+            if exec_blob is not None:
+                xname = f"e_{i:05d}.xlaexec"
+                with open(os.path.join(path, ENTRY_DIR, xname),
+                          "wb") as f:
+                    f.write(exec_blob)
+                rec["executable"] = xname
+        rec["compile_s"] = round(time.perf_counter() - t0, 4)
+        manifest["entries"].append(rec)
     with open(os.path.join(path, MANIFEST), "w") as f:
         json.dump(manifest, f, indent=2, sort_keys=True)
         f.write("\n")
@@ -350,11 +292,9 @@ def _compile_exported(exported, args: tuple):
 
 def _serialize_executable(name: str, compiled) -> bytes | None:
     """Pickle a ``jax.stages.Compiled`` (PjRt executable + arg/out trees);
-    None when this jax/backend cannot serialize executables."""
-    if not _HAVE_EXEC:
-        return None
+    None when this backend cannot serialize executables."""
     try:
-        blob, in_tree, out_tree = _jax_se.serialize(compiled)
+        blob, in_tree, out_tree = serialize_executable.serialize(compiled)
         return pickle.dumps((blob, in_tree, out_tree))
     except Exception as ex:
         warnings.warn(
@@ -378,6 +318,22 @@ def _signature_matches(compiled, args: tuple) -> bool:
         )
     except Exception:  # malformed args_info: treat as a miss, not an error
         return False
+
+
+def accepts(compiled, args: tuple) -> bool:
+    """True when the warmed executable ``compiled`` takes ``args`` as they
+    stand: same shapes and dtypes, and every committed array already on the
+    devices it was compiled for.  Callers check this BEFORE the call and
+    take the jitted path on a miss; once the call is made (and its donated
+    operands consumed), any error it raises is the caller's to see."""
+    if not _signature_matches(compiled, args):
+        return False
+    want = jax.tree_util.tree_leaves(compiled.input_shardings[0])
+    have = jax.tree_util.tree_leaves(args)
+    return len(want) == len(have) and all(
+        not isinstance(x, jax.Array) or not x.committed
+        or x.sharding.device_set == w.device_set
+        for x, w in zip(have, want))
 
 
 class AOTArtifact:
@@ -410,12 +366,12 @@ class AOTArtifact:
         """The deserialized ``jax.export.Exported`` for one entry (None when
         absent/unavailable)."""
         rec = self._by_name.get(name)
-        if rec is None or not rec.get("exported") or not _HAVE_EXPORT:
+        if rec is None or not rec.get("exported"):
             return None
         try:
             with open(os.path.join(self.path, ENTRY_DIR, rec["file"]),
                       "rb") as f:
-                return _jax_export.deserialize(f.read())
+                return jax_export.deserialize(f.read())
         except Exception as ex:
             warnings.warn(
                 f"AOT entry {name!r}: failed to deserialize "
@@ -431,13 +387,14 @@ class AOTArtifact:
         disagree with the baked input signature (the caller then takes the
         StableHLO-recompile tier)."""
         rec = self._by_name.get(name)
-        if rec is None or not rec.get("executable") or not _HAVE_EXEC:
+        if rec is None or not rec.get("executable"):
             return None
         try:
             with open(os.path.join(self.path, ENTRY_DIR,
                                    rec["executable"]), "rb") as f:
                 blob, in_tree, out_tree = pickle.load(f)
-            loaded = _jax_se.deserialize_and_load(blob, in_tree, out_tree)
+            loaded = serialize_executable.deserialize_and_load(
+                blob, in_tree, out_tree)
         except Exception as ex:
             warnings.warn(
                 f"AOT entry {name!r}: executable load failed "
@@ -485,20 +442,10 @@ def load_artifact(
     path: str,
     *,
     expected_key: dict | None = None,
-    enable_cache: bool = False,
 ) -> AOTArtifact | None:
     """Load + key-validate a deploy artifact; ``None`` (with a warning) on
     any mismatch or unreadable manifest — the graceful-JIT-fallback
-    contract.
-
-    ``enable_cache=True`` additionally turns on the artifact's persistent
-    XLA compilation cache *globally* for the rest of the process, so that
-    re-traces of covered programs become cache hits.  It is off by
-    default: warmed workers deserialize their executables from the
-    ``jax.export`` blobs and never need the cache, and cache-HIT
-    recompiles of the large fleet-step program segfault jaxlib 0.4.3x on
-    CPU.  Prefer scoping cache use explicitly with the
-    ``compilation_cache(...)`` context manager."""
+    contract."""
     manifest_path = os.path.join(path, MANIFEST)
     try:
         with open(manifest_path) as f:
@@ -521,6 +468,4 @@ def load_artifact(
             stacklevel=2,
         )
         return None
-    if enable_cache:
-        enable_compilation_cache(os.path.join(path, XLA_CACHE_DIR))
     return AOTArtifact(path, manifest)

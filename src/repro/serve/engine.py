@@ -253,19 +253,12 @@ class ServingEngine:
         args = (self._tables, self._bank,
                 jnp.asarray(self._param_rows[owner]), jnp.asarray(owner),
                 jnp.asarray(self._thresholds[owner]), jnp.asarray(batch))
-        out = None
         fn = self._exec.get((b_pad, t))
-        if fn is not None:  # prewarmed executable; JIT is the safety net
-            try:
-                out = fn(*args)
-            except AssertionError:
-                # sanitizer verdicts (guards.GuardViolation) must surface,
-                # not silently demote the executable to a JIT recompile
-                raise
-            except Exception:
-                self._exec.pop((b_pad, t), None)
-        if out is None:
-            out = _serve_dispatch(*args, self._cfg)
+        if fn is not None and not aot_mod.accepts(fn, args):
+            self._exec.pop((b_pad, t))
+            fn = None
+        out = fn(*args) if fn is not None else _serve_dispatch(*args,
+                                                               self._cfg)
         frames, scores, preds = out
 
         frames_np, scores_np, preds_np = (np.asarray(x) for x in

@@ -641,7 +641,7 @@ class StreamingFleet:
         # pre-compiled step/adapt executables — loaded from a serialized
         # deploy artifact or lowered+compiled here ahead of traffic — keyed
         # by (device, tile sessions, bucket); the hot loops prefer them and
-        # fall back to the jitted callables on any signature mismatch
+        # take the jitted callables when one does not accept its operands
         self._exec: dict[tuple, jax.stages.Compiled] = {}
         self._adapt_exec: dict[tuple, jax.stages.Compiled] = {}
         # faults=None keeps the partial's jaxpr IDENTICAL to the fault-free
@@ -1092,21 +1092,16 @@ class StreamingFleet:
         return fleet
 
     def _call_step(self, t_pad: int, sl: slice, dev, args: tuple):
-        """One tile step through the warmed executable when one matches,
-        else the jitted callable (also the safety net: an executable whose
-        placement/signature no longer matches is dropped, not fatal)."""
+        """One tile step through the warmed executable when it accepts
+        these operands (signature and placement, checked before the call),
+        else the jitted callable.  An error raised by the executable
+        itself propagates: its donated state is already consumed."""
         key = (dev, sl.stop - sl.start, t_pad)
         fn = self._exec.get(key)
         if fn is not None:
-            try:
+            if aot_mod.accepts(fn, args):
                 return fn(*args)
-            except AssertionError:
-                # sanitizer verdicts (guards.GuardViolation is an
-                # AssertionError) must surface, not silently demote the
-                # warmed executable to a JIT recompile
-                raise
-            except Exception:
-                self._exec.pop(key, None)
+            self._exec.pop(key)
         self._shapes_seen.add(t_pad)
         return self._step(*args)
 
@@ -1496,17 +1491,10 @@ class StreamingFleet:
             )
             akey = (d, sl.stop - sl.start)
             fn = self._adapt_exec.get(akey)
-            if fn is not None:
-                try:
-                    self._state_t[k], app = fn(*args)
-                    self._dirty_t[k] = True
-                    applied.append(app)
-                    continue
-                except AssertionError:  # sanitizer verdicts must surface
-                    raise
-                except Exception:
-                    self._adapt_exec.pop(akey, None)
-            self._state_t[k], app = self._adapt_step(*args)
+            if fn is not None and not aot_mod.accepts(fn, args):
+                self._adapt_exec.pop(akey)
+                fn = None
+            self._state_t[k], app = (fn or self._adapt_step)(*args)
             self._dirty_t[k] = True
             applied.append(app)
         return np.concatenate([np.asarray(a) for a in applied])[:self._n]

@@ -345,16 +345,10 @@ class ElasticFleet(StreamingFleet):
                 ) + tuple(jax.device_put(r, d) for r in rows)
         akey = (d, sl.stop - sl.start)
         fn = self._slot_exec.get(akey)
-        if fn is not None:
-            try:
-                self._state_t[k] = fn(*args)
-            except AssertionError:  # sanitizer verdicts must surface
-                raise
-            except Exception:
-                self._slot_exec.pop(akey, None)
-                self._state_t[k] = self._slot_write_jit(*args)
-        else:
-            self._state_t[k] = self._slot_write_jit(*args)
+        if fn is not None and not aot_mod.accepts(fn, args):
+            self._slot_exec.pop(akey)
+            fn = None
+        self._state_t[k] = (fn or self._slot_write_jit)(*args)
         self._dirty_t[k] = True
         self._filled_h[slot] = int(rows[1])
         self._fidx_h[slot] = int(rows[2])
@@ -387,16 +381,10 @@ class ElasticFleet(StreamingFleet):
                 jax.device_put(np.int32(slot - sl.start), d))
         akey = (d, sl.stop - sl.start)
         fn = self._read_exec.get(akey)
-        rows = None
-        if fn is not None:
-            try:
-                rows = fn(*args)
-            except AssertionError:  # sanitizer verdicts must surface
-                raise
-            except Exception:
-                self._read_exec.pop(akey, None)
-        if rows is None:
-            rows = self._slot_read_jit(*args)
+        if fn is not None and not aot_mod.accepts(fn, args):
+            self._read_exec.pop(akey)
+            fn = None
+        rows = (fn or self._slot_read_jit)(*args)
         counts, _, _, rows9, am_c, am_n, lastf, lasts, hasf = (
             np.asarray(r) for r in rows)
         has_am = self._am_counts0 is not None

@@ -129,6 +129,44 @@ def test_artifact_roundtrip_bitexact(tmp_path, variant, backend,
     assert _decisions(got) == want
 
 
+class _Failing:
+    """A warmed executable whose call fails at run time."""
+
+    def __init__(self, compiled):
+        self._compiled = compiled
+
+    def __getattr__(self, name):
+        return getattr(self._compiled, name)
+
+    def __call__(self, *args):
+        raise RuntimeError("executable failed")
+
+
+def test_warmed_executable_checked_before_call():
+    """A warmed step executable that does not accept the operands (here:
+    the other bucket's) is dropped before the call and the jitted step
+    serves the push bit-exactly; one that accepts them but fails at run time
+    raises — its donated state is gone, so nothing re-runs it under JIT."""
+    pipe = _trained("sparse_compim", seed=2)
+    kw = dict(buckets=(WINDOW, 2 * WINDOW))
+    chunks = _chunks(3, 2)
+    want = _decisions(StreamingFleet({"p": pipe}, ["p"] * 2, **kw)
+                      .push(chunks))
+
+    warm = StreamingFleet({"p": pipe}, ["p"] * 2, **kw)
+    warm.warmup()
+    small, big = sorted(warm._exec, key=lambda key: key[2])
+    warm._exec[small] = warm._exec[big]
+    assert _decisions(warm.push(chunks)) == want
+    assert small not in warm._exec and big in warm._exec
+
+    failing = StreamingFleet({"p": pipe}, ["p"] * 2, **kw)
+    failing.warmup()
+    failing._exec[small] = _Failing(failing._exec[small])
+    with pytest.raises(RuntimeError, match="executable failed"):
+        failing.push(chunks)
+
+
 def test_entries_ship_xla_executables(tmp_path):
     """Every exported entry also carries a serialized PjRt executable, and
     the load path hands it back without an XLA recompile; a signature
@@ -147,6 +185,39 @@ def test_entries_ship_xla_executables(tmp_path):
                                      s.dtype) for s in good)
     assert art.load_executable(name, good) is not None
     assert art.load_executable(name, bad) is None
+
+
+def test_artifact_rebuilt_under_warm_persistent_cache(tmp_path):
+    """A second ``save_aot`` whose compiles the persistent compilation
+    cache could serve still ships executables that load and run bit-exactly
+    (a cache-served executable does not re-serialize into a runnable
+    one)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    pipe = _trained("sparse_compim", seed=5)
+    kw = dict(buckets=(WINDOW,))
+    chunks = _chunks(13, 2)
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    cc.reset_cache()
+    try:
+        for name in ("aot1", "aot2"):
+            jax.clear_caches()
+            StreamingFleet({"p": pipe}, ["p"] * 2, **kw).save_aot(
+                str(tmp_path / name))
+        warm = StreamingFleet({"p": pipe}, ["p"] * 2, **kw)
+        stats = warm.warmup(aot=aot_mod.load_artifact(str(tmp_path / "aot2")))
+        got = _decisions(warm.push(chunks))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev[1])
+        cc.reset_cache()
+    assert stats["loaded"] > 0 and stats["compiled"] == 0
+    want = StreamingFleet({"p": pipe}, ["p"] * 2, **kw).push(chunks)
+    assert got == _decisions(want)
 
 
 def test_faulted_fleet_artifact_roundtrip(tmp_path):

@@ -38,7 +38,8 @@ def test_small_mesh_lower_compile(kind):
     from repro.optim import adamw
     from repro.runtime import steps as steps_mod
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config("qwen3-0.6b").reduced(d_model=256, n_heads=4,
                                            n_kv_heads=2, head_dim=64,
                                            vocab=1024, d_ff=512)
@@ -87,7 +88,8 @@ def test_multipod_axis_shards():
     from repro.optim import adamw
     from repro.runtime import steps as steps_mod
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_config("qwen3-0.6b").reduced(d_model=256, n_heads=4,
                                            n_kv_heads=2, head_dim=64,
                                            vocab=1024, d_ff=512)

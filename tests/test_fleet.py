@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.core.pipeline import HDCConfig, HDCPipeline, VARIANTS
+from repro.launch.mesh import make_mesh
 from repro.serve.dispatch import datapath_key
 from repro.serve.engine import SeizureSession, ServingEngine
 from repro.serve.fleet import StreamingFleet
@@ -332,7 +333,7 @@ def test_fleet_on_mesh_matches_unsharded():
     pipes = {"a": _trained("sparse_compim", seed=0, temporal_threshold=4),
              "b": _trained("sparse_compim", seed=1, temporal_threshold=6)}
     owners = ["a", "b", "a", "b"]
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     plain = StreamingFleet(pipes, owners, buckets=(16, 32))
     sharded = StreamingFleet(pipes, owners, buckets=(16, 32), mesh=mesh)
     rng = np.random.default_rng(11)

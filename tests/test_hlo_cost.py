@@ -84,7 +84,8 @@ def test_collectives_counted_in_sharded_module(tmp_path):
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.runtime.hlo_cost import analyze_hlo
-        mesh = jax.make_mesh((4,), ("d",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("d",))
         sh = NamedSharding(mesh, P(None, "d"))
 
         def f(w, x):

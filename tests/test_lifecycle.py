@@ -457,18 +457,22 @@ def test_sigterm_writes_final_checkpoint_and_exits_clean(tmp_path):
     ckpt_dir = str(tmp_path / "ckpt")
     env = dict(os.environ, PYTHONPATH="src", REPRO_FLEET_TILE="64",
                JAX_PLATFORMS="cpu")
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "repro.launch.serve", "--hdc-fleet",
-         "--sessions", "4", "--patients", "1", "--rounds", "100000",
-         "--chunk", "64", "--ckpt-dir", ckpt_dir, "--ckpt-every", "2"],
-        env=env, cwd=os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+    # output goes to a file: an undrained pipe would block a chatty server
+    # (e.g. compile-cache log lines) before it ever reaches a checkpoint
+    log_path = tmp_path / "serve.log"
+    with open(log_path, "w") as log:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "repro.launch.serve", "--hdc-fleet",
+             "--sessions", "4", "--patients", "1", "--rounds", "100000",
+             "--chunk", "64", "--ckpt-dir", ckpt_dir, "--ckpt-every", "2"],
+            env=env, cwd=os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))),
+            stdout=log, stderr=subprocess.STDOUT, text=True)
     try:
         deadline = time.time() + 180
         while time.time() < deadline:
             if proc.poll() is not None:
-                pytest.fail("serve exited early:\n" + proc.communicate()[0])
+                pytest.fail("serve exited early:\n" + log_path.read_text())
             if os.path.isdir(ckpt_dir) and any(
                     d.startswith("step_") and not d.endswith(".tmp")
                     for d in os.listdir(ckpt_dir)):
@@ -477,11 +481,12 @@ def test_sigterm_writes_final_checkpoint_and_exits_clean(tmp_path):
         else:
             pytest.skip("serve did not reach first checkpoint in time")
         proc.send_signal(signal.SIGTERM)
-        out, _ = proc.communicate(timeout=120)
+        proc.wait(timeout=120)
     finally:
         if proc.poll() is None:
             proc.kill()
-            proc.communicate()
+            proc.wait()
+    out = log_path.read_text()
     assert proc.returncode == 0, out
     assert "caught SIGTERM" in out
     steps = [d for d in os.listdir(ckpt_dir)

@@ -11,6 +11,7 @@ import pytest
 
 from repro.core import online
 from repro.core.pipeline import BACKENDS, HDCConfig, HDCPipeline, VARIANTS
+from repro.launch.mesh import make_mesh
 from repro.serve.engine import SeizureSession
 from repro.serve.fleet import StreamingFleet
 
@@ -363,7 +364,7 @@ def test_fleet_checkpoint_elastic_onto_mesh(tmp_path):
     plain = StreamingFleet(pipes, owners, buckets=(16, 32))
     plain.push([_chunk(rng, 20), _chunk(rng, 45)])
     plain.save(str(tmp_path))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sharded = StreamingFleet(pipes, owners, buckets=(16, 32), mesh=mesh)
     sharded.restore(str(tmp_path))
     chunks = [_chunk(rng, 40), _chunk(rng, 40)]
