@@ -9,14 +9,6 @@ per-decision latency, plus the fleet/baseline speedup row the CI
 perf-regression gate reads from BENCH_fleet.json, and a ``fleet_codes`` row
 for the zero-scatter pre-stacked ``push_codes`` ingest path.
 
-At the largest S the module additionally reports a PER-STAGE breakdown of
-the steady-state push (``stage_ingest`` / ``stage_spatial`` /
-``stage_temporal`` / ``stage_am`` rows): each stage is timed as its own
-jitted sub-benchmark on one session tile and scaled by the tile count, and
-its share of the measured push time rides in the ``derived`` column — the
-committed artifact behind the "spatial stage no longer dominant" claim
-(the CI gate bounds the spatial share, see check_fleet_regression.py).
-
 Methodology: both sides run the SAME repeat count and statistic (min over
 iters — on this shared container scheduler bursts inflate single samples
 3-10x and noise only ever adds, so the minimum estimates the true cost;
@@ -92,50 +84,6 @@ def _time(fn, iters: int) -> float:
     return min(times)
 
 
-def _stage_rows(fleet: StreamingFleet, batch: np.ndarray, s: int,
-                iters: int) -> list[dict]:
-    """Per-stage sub-benchmarks of one steady-state push at fleet scale S.
-
-    The stage callables come from ``StreamingFleet.stage_probes`` — they
-    live next to the step implementation, so refactors of the fleet's tile
-    internals keep the probes in sync; this module only times them.  The
-    reference push and the stages are sampled INTERLEAVED (one round-robin
-    cycle per iteration, min over iterations): a scheduler burst longer
-    than one cycle inflates every term together, so the share ratios the
-    CI gate reads stay stable where separately-timed medians flaked.
-    Stages overlap/fuse inside the real step, so shares need not sum
-    to 100%.
-    """
-    probes = fleet.stage_probes(batch)
-
-    def push_once():
-        jax.block_until_ready(
-            [r.tiles for r in fleet.push_codes_raw(batch)])
-
-    push_once()  # warm
-    samples: dict[str, list[float]] = {"push": []}
-    for name, _ in probes.items():
-        samples[name] = []
-    for _ in range(iters):
-        for name, fn in [("push", push_once)] + [
-                (n, f) for n, (f, _) in probes.items()]:
-            t0 = time.perf_counter()
-            fn()
-            samples[name].append(time.perf_counter() - t0)
-    t_push = min(samples["push"])
-    rows = []
-    for name, (fn, scale) in probes.items():
-        t = min(samples[name]) * scale
-        how = "host, 1 round" if name == "ingest" else f"device, x{scale} tiles"
-        rows.append({
-            "name": f"fleet.S{s}.stage_{name}",
-            "us_per_call": f"{t * 1e6:.0f}",
-            "derived": (f"share={100 * t / t_push:.1f}% of steady-state "
-                        f"push ({how})"),
-        })
-    return rows
-
-
 def run() -> list[dict]:
     cfg, s_list, iters = _config()
     pipe = _trained(cfg)
@@ -206,8 +154,6 @@ def run() -> list[dict]:
             "derived": (f"{t_base / t_codes:.2f}x sessions/s vs looped "
                         f"baseline (pre-stacked push_codes ingest)"),
         })
-        if s == s_list[-1]:  # per-stage breakdown at fleet scale
-            rows.extend(_stage_rows(fleet, batch, s, iters))
     return rows
 
 

@@ -14,12 +14,6 @@ fails only on KNOWN rows that regressed, went missing, or stopped parsing.
 The committed reference itself is held to strict parsing: it is a curated
 artifact, and a malformed row there is a repo bug, not a perf signal.
 
-The gate also reads the fresh run's per-stage breakdown
-(``fleet.*.stage_*`` rows, another same-process ratio): the code-domain
-datapath's whole point is that the spatial gather+bundle stops dominating
-the step, so a fresh ``stage_spatial`` share above ``--max-spatial-share``
-(default 50% of steady-state push time) fails the gate.
-
 With ``--coldstart-fresh``/``--coldstart-reference`` the same known-row
 speedup machinery additionally gates BENCH_coldstart.json's
 ``coldstart.*.speedup`` ratio rows (warm-cache / serialized-executable vs
@@ -51,7 +45,7 @@ accuracy cliff at 1-2 failed channels all fail CI.
 Usage::
 
     python -m benchmarks.check_fleet_regression FRESH.json REFERENCE.json \
-        [--tolerance 0.25] [--max-spatial-share 0.5] \
+        [--tolerance 0.25] \
         [--coldstart-fresh BENCH_coldstart.json \
          --coldstart-reference benchmarks/BENCH_coldstart_tiny.json] \
         [--churn-fresh BENCH_churn.json \
@@ -68,7 +62,6 @@ import re
 import sys
 
 _SPEEDUP = re.compile(r"^([0-9.]+)x ")
-_SHARE = re.compile(r"^share=([0-9.]+)% ")
 
 # rows whose derived string must start with "ok" for the gate to pass
 COLDSTART_STATUS_ROWS = ("coldstart.bitexact", "coldstart.fallback")
@@ -108,24 +101,6 @@ def speedups(path: str, *, prefix: str = "fleet.", strict: bool = True
             bad[name] = row
             continue
         out[name] = float(m.group(1))
-    return out, bad
-
-
-def stage_shares(path: str) -> tuple[dict[str, float], dict[str, dict]]:
-    """``fleet.*.stage_*`` rows -> fractional share of steady-state push
-    (plus the rows whose derived string did not parse)."""
-    payload = _load(path)
-    out: dict[str, float] = {}
-    bad: dict[str, dict] = {}
-    for row in payload.get("rows", []):
-        name = row.get("name", "")
-        if not (name.startswith("fleet.") and ".stage_" in name):
-            continue
-        m = _SHARE.match(row.get("derived", ""))
-        if not m:
-            bad[name] = row
-            continue
-        out[name] = float(m.group(1)) / 100.0
     return out, bad
 
 
@@ -197,9 +172,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("reference", help="committed reference BENCH_fleet.json")
     ap.add_argument("--tolerance", type=float, default=0.25,
                     help="allowed fractional regression (default 0.25)")
-    ap.add_argument("--max-spatial-share", type=float, default=0.5,
-                    help="fail when the fresh stage_spatial share of the "
-                         "steady-state push exceeds this (default 0.5)")
     ap.add_argument("--coldstart-fresh", default=None,
                     help="BENCH_coldstart.json from this run (enables the "
                          "cold-start ratio + correctness gate)")
@@ -230,25 +202,6 @@ def main(argv: list[str] | None = None) -> int:
 
     failed = gate_speedups(args.fresh, args.reference,
                            prefix="fleet.", tolerance=args.tolerance)
-
-    shares, shares_bad = stage_shares(args.fresh)
-    for name in sorted(shares_bad):
-        print(f"warning: {name}: unparseable stage row "
-              f"{shares_bad[name]!r}; skipping", file=sys.stderr)
-    spatial = {n: v for n, v in shares.items() if n.endswith("stage_spatial")}
-    if not spatial:
-        print("no fleet.*.stage_spatial row in fresh run "
-              "(per-stage breakdown missing)", file=sys.stderr)
-        return 1
-    for name, share in sorted(shares.items()):
-        note = ""
-        if name in spatial:
-            ok = share <= args.max_spatial_share
-            note = (f" (cap {args.max_spatial_share:.0%}) -> "
-                    f"{'OK' if ok else 'DOMINANT'}")
-            if not ok:
-                failed.append(name)
-        print(f"{name}: {share:.1%} of steady-state push{note}")
 
     if args.coldstart_fresh:
         failed += gate_speedups(args.coldstart_fresh,

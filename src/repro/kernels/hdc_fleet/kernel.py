@@ -145,10 +145,11 @@ def fleet_counts_pallas(tables: jax.Array, owner: jax.Array,
     masked = chan_mask is not None
     # four codes per int32 word, little end first: SMEM holds 32-bit scalars
     cw = -(-c // 4)
-    packed = jnp.pad(codes, ((0, 0), (0, 0), (0, cw * 4 - c))).astype(
-        jnp.int32).reshape(s, t32, cw, 4)
-    packed = (packed[..., 0] | (packed[..., 1] << 8) | (packed[..., 2] << 16)
-              | (packed[..., 3] << 24))
+    with jax.named_scope("pack_codes"):
+        packed = jnp.pad(codes, ((0, 0), (0, 0), (0, cw * 4 - c))).astype(
+            jnp.int32).reshape(s, t32, cw, 4)
+        packed = (packed[..., 0] | (packed[..., 1] << 8)
+                  | (packed[..., 2] << 16) | (packed[..., 3] << 24))
     kernel = functools.partial(_fleet_kernel, mode=mode, channels=c,
                                n_codes=k, dim=dim, threshold=threshold,
                                masked=masked)
@@ -180,6 +181,8 @@ def fleet_counts_pallas(tables: jax.Array, owner: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, kp1, 32, w), jnp.int32),
         interpret=interpret,
+        # the custom call's HLO name, and so the kernel's name in profiles
+        name="hdc_fleet_counts",
     )(*inputs)
     # (bit, word) layout -> standard d = word * 32 + bit order
     return counts.transpose(0, 1, 3, 2).reshape(s, kp1, dim)

@@ -263,6 +263,8 @@ def run_hdc_fleet(args) -> None:
             print(f"  ... {len(ev) - 20} more event(s)")
     print(f"compiled step executables: {fleet.compile_count} "
           f"(buckets: {fleet._buckets})")
+    print("fleet counters: " + ", ".join(
+        f"{k}={v}" for k, v in fleet.counters.items()))
     if args.ckpt_dir:
         path = fleet.save(args.ckpt_dir)
         print(f"saved fleet checkpoint -> {path}")

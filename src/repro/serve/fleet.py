@@ -88,6 +88,7 @@ from typing import Hashable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.ckpt import checkpoint as ckpt
 from repro.core import hv, online
@@ -313,88 +314,95 @@ def _fleet_step(
                 counts_in, k_cnt, fault_ber[2],
                 bits=rel_faults.counter_bits(faults, cfg.window),
                 mode=faults.mode)
-    if use_kernel:
-        # fused kernel: codes in, slot counts out — the table gather,
-        # spatial bundle, bit transpose and masked popcount stay in VMEM
-        seg = fleet_ops.fleet_counts_fused(tables, owner, chunk,
-                                           state.filled, lengths, cfg,
-                                           tables_xor=tables_xor,
-                                           chan_mask=chan_mask)
-    else:
-        if tables_xor is not None:
-            tables = tables ^ tables_xor
-        words = dispatch.owner_spatial_codes(tables, owner, chunk, cfg,
-                                             chan_mask)
-        seg = fleet_ops.fleet_counts(words, state.filled, lengths, cfg)
-    seg = shd.constrain(seg, ("batch", None, None), ctx)  # (S, K+1, D) int32
+    with jax.named_scope("spatial_temporal"):
+        if use_kernel:
+            # fused kernel: codes in, slot counts out — the table gather,
+            # spatial bundle, bit transpose and masked popcount stay in VMEM
+            seg = fleet_ops.fleet_counts_fused(tables, owner, chunk,
+                                               state.filled, lengths, cfg,
+                                               tables_xor=tables_xor,
+                                               chan_mask=chan_mask)
+        else:
+            if tables_xor is not None:
+                tables = tables ^ tables_xor
+            words = dispatch.owner_spatial_codes(tables, owner, chunk, cfg,
+                                                 chan_mask)
+            seg = fleet_ops.fleet_counts(words, state.filled, lengths, cfg)
+        # (S, K+1, D) int32
+        seg = shd.constrain(seg, ("batch", None, None), ctx)
 
     n_emit = (state.filled + lengths) // cfg.window  # (S,)
     # the carried accumulator belongs to the FIRST completed frame when the
     # session emits, and to the tail otherwise
     emits = n_emit > 0
-    frame_counts = seg[:, :-1].at[:, 0].add(
-        jnp.where(emits[:, None], counts_in, 0)
-    )
-    if cfg.variant == "dense":
-        frames = hv.majority_pack(frame_counts, cfg.window, cfg.dim)
-    else:
-        frames = hv.threshold_pack(frame_counts, thresholds[:, None, None])
-    ecc_counts = None
-    if faults is None:
-        scores = dispatch.owner_am_scores(frames, state.class_rows[:, None],
-                                          cfg)
-    else:
-        rows = state.class_rows
-        check = (rel_ecc.encode(rows, faults.ecc)
-                 if faults.ecc != "none" else None)
-        if faults.am:
-            k_am_d, k_am_c = jax.random.split(k_am)
-            rows = rel_faults.flip_words(rows, k_am_d, fault_ber[1],
-                                         mode=faults.mode)
-            if check is not None:
-                check = rel_faults.flip_words(
-                    check, k_am_c, fault_ber[1],
-                    bits=rel_ecc.n_check_bits(faults.ecc), mode=faults.mode)
-        if check is not None:
-            scores, ecc_counts = dispatch.owner_am_scores_protected(
-                frames, rows, check, cfg, faults.ecc)
+    with jax.named_scope("threshold_pack"):
+        frame_counts = seg[:, :-1].at[:, 0].add(
+            jnp.where(emits[:, None], counts_in, 0)
+        )
+        if cfg.variant == "dense":
+            frames = hv.majority_pack(frame_counts, cfg.window, cfg.dim)
         else:
-            scores = dispatch.owner_am_scores(frames, rows[:, None], cfg)
-        if ecc_counts is None:
-            ecc_counts = jnp.zeros((s, 3), jnp.int32)
-        ecc_counts = shd.constrain(ecc_counts, ("batch", None), ctx)
-    new_counts = seg[:, -1] + jnp.where(emits[:, None], 0, counts_in)
-    # capture each emitting session's LAST completed frame for adapt
-    sidx = jnp.arange(s, dtype=jnp.int32)
-    last_slot = jnp.maximum(n_emit - 1, 0)
-    new_state = replace(
-        state,
-        counts=shd.constrain(new_counts, _STATE_AXES["counts"], ctx),
-        filled=shd.constrain(
-            state.filled + lengths - n_emit * cfg.window,
-            _STATE_AXES["filled"], ctx,
-        ),
-        frame_index=shd.constrain(
-            state.frame_index + n_emit, _STATE_AXES["frame_index"], ctx
-        ),
-        last_frame=shd.constrain(
-            jnp.where(emits[:, None], frames[sidx, last_slot],
-                      state.last_frame),
-            _STATE_AXES["last_frame"], ctx,
-        ),
-        last_scores=shd.constrain(
-            # int32 pinned: the popcount scores promote to int64 under
-            # JAX_ENABLE_X64, which would drift the carried state dtype
-            # (and the jit cache key) after the first step
-            jnp.where(emits[:, None], scores[sidx, last_slot],
-                      state.last_scores).astype(jnp.int32),
-            _STATE_AXES["last_scores"], ctx,
-        ),
-        has_frame=shd.constrain(
-            state.has_frame | emits.astype(jnp.int32),
-            _STATE_AXES["has_frame"], ctx,
-        ),
-    )
+            frames = hv.threshold_pack(frame_counts,
+                                       thresholds[:, None, None])
+    with jax.named_scope("am_scores"):
+        ecc_counts = None
+        if faults is None:
+            scores = dispatch.owner_am_scores(
+                frames, state.class_rows[:, None], cfg)
+        else:
+            rows = state.class_rows
+            check = (rel_ecc.encode(rows, faults.ecc)
+                     if faults.ecc != "none" else None)
+            if faults.am:
+                k_am_d, k_am_c = jax.random.split(k_am)
+                rows = rel_faults.flip_words(rows, k_am_d, fault_ber[1],
+                                             mode=faults.mode)
+                if check is not None:
+                    check = rel_faults.flip_words(
+                        check, k_am_c, fault_ber[1],
+                        bits=rel_ecc.n_check_bits(faults.ecc),
+                        mode=faults.mode)
+            if check is not None:
+                scores, ecc_counts = dispatch.owner_am_scores_protected(
+                    frames, rows, check, cfg, faults.ecc)
+            else:
+                scores = dispatch.owner_am_scores(frames, rows[:, None], cfg)
+            if ecc_counts is None:
+                ecc_counts = jnp.zeros((s, 3), jnp.int32)
+            ecc_counts = shd.constrain(ecc_counts, ("batch", None), ctx)
+    with jax.named_scope("state_update"):
+        new_counts = seg[:, -1] + jnp.where(emits[:, None], 0, counts_in)
+        # capture each emitting session's LAST completed frame for adapt
+        sidx = jnp.arange(s, dtype=jnp.int32)
+        last_slot = jnp.maximum(n_emit - 1, 0)
+        new_state = replace(
+            state,
+            counts=shd.constrain(new_counts, _STATE_AXES["counts"], ctx),
+            filled=shd.constrain(
+                state.filled + lengths - n_emit * cfg.window,
+                _STATE_AXES["filled"], ctx,
+            ),
+            frame_index=shd.constrain(
+                state.frame_index + n_emit, _STATE_AXES["frame_index"], ctx
+            ),
+            last_frame=shd.constrain(
+                jnp.where(emits[:, None], frames[sidx, last_slot],
+                          state.last_frame),
+                _STATE_AXES["last_frame"], ctx,
+            ),
+            last_scores=shd.constrain(
+                # int32 pinned: the popcount scores promote to int64 under
+                # JAX_ENABLE_X64, which would drift the carried state dtype
+                # (and the jit cache key) after the first step
+                jnp.where(emits[:, None], scores[sidx, last_slot],
+                          state.last_scores).astype(jnp.int32),
+                _STATE_AXES["last_scores"], ctx,
+            ),
+            has_frame=shd.constrain(
+                state.has_frame | emits.astype(jnp.int32),
+                _STATE_AXES["has_frame"], ctx,
+            ),
+        )
     out = FleetOut(frames=frames, scores=scores)
     if faults is None:
         return new_state, out
@@ -431,6 +439,30 @@ def _fleet_adapt(
         class_rows=shd.constrain(class_rows, _STATE_AXES["class_rows"], ctx),
     )
     return new_state, applied
+
+
+def _push_span(push):
+    """Wrap a fleet push method in a ``fleet.push`` profiler span that
+    records how many rounds (device steps) the push made."""
+    @functools.wraps(push)
+    def spanned(self, *args, **kwargs):
+        with TraceAnnotation("fleet.push") as span:
+            rounds = push(self, *args, **kwargs)
+            span.set_metadata(rounds=len(rounds))
+        return rounds
+    return spanned
+
+
+# sharding axes of what ``_rounds`` puts per tile: codes, lengths, fault seed
+_H2D_AXES = (("batch", None, None), ("batch",), ())
+
+
+def _named(name: str, fn: functools.partial) -> functools.partial:
+    """``fn`` under ``name``, which its jit's XLA module takes
+    (``jit_<name>``): a bare partial has none, and profiles would show the
+    module as ``jit__unknown``."""
+    fn.__name__ = name
+    return fn
 
 
 class StreamingFleet:
@@ -644,13 +676,17 @@ class StreamingFleet:
         # take the jitted callables when one does not accept its operands
         self._exec: dict[tuple, jax.stages.Compiled] = {}
         self._adapt_exec: dict[tuple, jax.stages.Compiled] = {}
+        # what the host moved and waited on, for ``counters``
+        self._counters = dict.fromkeys(
+            ("h2d_bytes", "d2h_bytes", "stage_waits", "jit_steps"), 0)
         # faults=None keeps the partial's jaxpr IDENTICAL to the fault-free
         # step — the fault path costs nothing unless a plan is configured
         # (and masked=False likewise keeps the mask-free jaxpr byte-exact)
         self._step = jax.jit(
-            functools.partial(_fleet_step, cfg=self._cfg, ctx=self._ctx,
-                              use_kernel=self._backend == "pallas",
-                              faults=self._plan, masked=self._masked),
+            _named("fleet_step", functools.partial(
+                _fleet_step, cfg=self._cfg, ctx=self._ctx,
+                use_kernel=self._backend == "pallas",
+                faults=self._plan, masked=self._masked)),
             donate_argnums=(0,),
         )
         # NOT donated: several state leaves pass through adapt untouched and
@@ -658,7 +694,8 @@ class StreamingFleet:
         # donation warning; adapt is rare relative to push, so the one
         # transient copy is the cheaper trade
         self._adapt_step = jax.jit(
-            functools.partial(_fleet_adapt, cfg=self._cfg, ctx=self._ctx),
+            _named("fleet_adapt", functools.partial(
+                _fleet_adapt, cfg=self._cfg, ctx=self._ctx)),
         )
 
     # -- state management ---------------------------------------------------
@@ -869,6 +906,20 @@ class StreamingFleet:
         jit_n = (cache_size() if cache_size is not None
                  else len(self._shapes_seen))
         return jit_n + len(self._exec)
+
+    @property
+    def counters(self) -> dict[str, int]:
+        """What the host moved and waited on since construction, as a
+        snapshot: ``h2d_bytes`` / ``d2h_bytes`` put on / copied back from
+        the devices by pushes and collections, ``stage_waits`` staging
+        slots whose previous step was still running (the push blocked on
+        it), and ``jit_steps`` steps that ran through the jitted callable
+        instead of a warmed executable (so recompiled where its shape was
+        new).  The same pushes are spanned for the profiler as
+        ``fleet.push`` > ``fleet.stage`` (> ``fleet.stage_wait``),
+        ``fleet.h2d``, ``fleet.dispatch``, and collections as
+        ``fleet.collect`` > ``fleet.d2h``, ``fleet.decode``."""
+        return dict(self._counters)
 
     @property
     def aot_count(self) -> int:
@@ -1097,13 +1148,20 @@ class StreamingFleet:
         else the jitted callable.  An error raised by the executable
         itself propagates: its donated state is already consumed."""
         key = (dev, sl.stop - sl.start, t_pad)
-        fn = self._exec.get(key)
-        if fn is not None:
-            if aot_mod.accepts(fn, args):
-                return fn(*args)
-            self._exec.pop(key)
-        self._shapes_seen.add(t_pad)
-        return self._step(*args)
+        tile = sl.start // (self._tile_slices[0].stop
+                            - self._tile_slices[0].start)
+        with TraceAnnotation("fleet.dispatch", tile=tile,
+                             bucket=t_pad) as span:
+            fn = self._exec.get(key)
+            if fn is not None and not aot_mod.accepts(fn, args):
+                self._exec.pop(key)
+                fn = None
+            if fn is None:
+                self._shapes_seen.add(t_pad)
+                self._counters["jit_steps"] += 1
+                fn = self._step
+            span.set_metadata(path="jit" if fn is self._step else "aot")
+            return fn(*args)
 
     # -- streaming ----------------------------------------------------------
 
@@ -1119,8 +1177,11 @@ class StreamingFleet:
         CPU backend's device_put aliases it zero-copy) before returning."""
         key = (slot, t_pad)
         busy = self._stage_busy[k].pop(key, None)
-        if busy is not None:
-            jax.block_until_ready(busy)
+        if busy is not None and not all(
+                x.is_ready() for x in jax.tree.leaves(busy)):
+            self._counters["stage_waits"] += 1
+            with TraceAnnotation("fleet.stage_wait"):
+                jax.block_until_ready(busy)
         if key not in self._stage_t[k]:
             sl = self._tile_slices[k]
             self._stage_t[k][key] = np.zeros(
@@ -1203,25 +1264,31 @@ class StreamingFleet:
             # (except a slot whose previous reader is still in flight)
             for k, (sl, d) in enumerate(
                     zip(self._tile_slices, self._tile_devs)):
-                stage = self._stage_buf(k, slot, t_pad)
-                hi = min(sl.stop, self._n)   # phantom rows: stale == masked
-                if hi > sl.start:
-                    stage[:hi - sl.start, :width] = big[sl.start:hi,
-                                                        pos:pos + width]
+                with TraceAnnotation("fleet.stage", tile=k):
+                    stage = self._stage_buf(k, slot, t_pad)
+                    hi = min(sl.stop, self._n)  # phantom rows: stale == masked
+                    if hi > sl.start:
+                        stage[:hi - sl.start, :width] = big[sl.start:hi,
+                                                            pos:pos + width]
+                host = [stage, round_len32[sl]]
+                if self._plan is not None:
+                    host.append(np.int32(rel_faults.step_seed(
+                        self._plan, tile=k, n_tiles=len(self._tile_slices),
+                        phase=phase)))
+                nbytes = sum(x.nbytes for x in host)
+                self._counters["h2d_bytes"] += nbytes
+                with TraceAnnotation("fleet.h2d", bytes=nbytes):
+                    puts = [self._put_tile(x, axes, d)
+                            for x, axes in zip(host, _H2D_AXES)]
                 args = (
                     self._state_t[k],
                     self._tables_t[k],
                     self._param_owner_t[k],
                     self._thresholds_t[k],
-                    self._put_tile(stage, ("batch", None, None), d),
-                    self._put_tile(round_len32[sl], ("batch",), d),
+                    *puts[:2],  # codes, lengths
                 )
                 if self._plan is not None:
-                    seed = rel_faults.step_seed(
-                        self._plan, tile=k, n_tiles=len(self._tile_slices),
-                        phase=phase)
-                    args += (self._ber_t[k],
-                             self._put_tile(np.int32(seed), (), d))
+                    args += (self._ber_t[k], *puts[2:])  # BER, fault seed
                 if self._masked:
                     args += (self._cmask_t[k],)
                 res = self._call_step(t_pad, sl, d, args)
@@ -1246,6 +1313,7 @@ class StreamingFleet:
             pos += max_bucket
         return rounds
 
+    @_push_span
     def push_raw(self, chunks: Sequence) -> list[FleetRound]:
         """Feed one (t_i, channels) uint8 chunk per session; zero host-side
         schedule work beyond O(S) per round.
@@ -1269,6 +1337,7 @@ class StreamingFleet:
             return []
         return self._rounds(self._pack(arrs, real_lengths), lengths)
 
+    @_push_span
     def push_codes_raw(self, batch, lengths: Sequence[int] | None = None
                        ) -> list[FleetRound]:
         """Zero-scatter ingest fast path: feed one pre-stacked (S, t, ch)
@@ -1318,26 +1387,33 @@ class StreamingFleet:
         argmax runs vectorized over all (session, slot) pairs and the Python
         loop touches only sessions that actually emitted."""
         out: list[list[FrameDecision]] = [[] for _ in range(self._n)]
-        for r in rounds:
-            if not r.n_emit.any():
-                continue
-            for sl, fo in zip(self._tile_slices, r.tiles):
-                ne = r.n_emit[sl]
-                if not ne.any():
+        with TraceAnnotation("fleet.collect"):
+            for r in rounds:
+                if not r.n_emit.any():
                     continue
-                frames = np.asarray(fo.frames)
-                scores = np.asarray(fo.scores)
-                preds = np.argmax(scores, axis=-1)         # (tile_s, K)
-                for i in np.nonzero(ne)[0]:
-                    g = sl.start + int(i)
-                    base = int(r.frame_base[g])
-                    out[g].extend(
-                        FrameDecision(frame_index=base + k,
-                                      scores=scores[i, k],
-                                      prediction=int(preds[i, k]),
-                                      frame_hv=frames[i, k])
-                        for k in range(int(ne[i]))
-                    )
+                for sl, fo in zip(self._tile_slices, r.tiles):
+                    ne = r.n_emit[sl]
+                    if not ne.any():
+                        continue
+                    with TraceAnnotation("fleet.d2h") as span:
+                        frames = np.asarray(fo.frames)
+                        scores = np.asarray(fo.scores)
+                        nbytes = frames.nbytes + scores.nbytes
+                        span.set_metadata(bytes=nbytes)
+                    self._counters["d2h_bytes"] += nbytes
+                    with TraceAnnotation("fleet.decode",
+                                         decisions=int(ne.sum())):
+                        preds = np.argmax(scores, axis=-1)  # (tile_s, K)
+                        for i in np.nonzero(ne)[0]:
+                            g = sl.start + int(i)
+                            base = int(r.frame_base[g])
+                            out[g].extend(
+                                FrameDecision(frame_index=base + k,
+                                              scores=scores[i, k],
+                                              prediction=int(preds[i, k]),
+                                              frame_hv=frames[i, k])
+                                for k in range(int(ne[i]))
+                            )
         return out
 
     def push(self, chunks: Sequence) -> list[list[FrameDecision]]:
@@ -1347,102 +1423,6 @@ class StreamingFleet:
         session, the decisions for every frame completed by this push.
         """
         return self.collect_decisions(self.push_raw(chunks))
-
-    # -- instrumentation ------------------------------------------------------
-
-    def stage_probes(self, batch) -> dict[str, tuple]:
-        """Per-stage sub-benchmarks of one steady push round, for the fleet
-        benchmark's breakdown rows (bench_fleet.py) — the stages live HERE so
-        the probe tracks the step implementation instead of reaching into
-        fleet internals from the benchmark.
-
-        ``batch`` is one (S, t, channels) uint8 code round (t <= max
-        bucket).  Returns ``{stage: (fn, scale)}``: ``fn()`` runs that stage
-        once on ONE session tile and blocks on the result; ``scale`` (the
-        tile count, 1 for the host-side ``ingest``) multiplies the time to
-        cover the whole fleet.  Each fn is pre-run once, so jit compilation
-        never pollutes the first timed call.  Stages overlap/fuse inside
-        the real jitted step, so their times need not sum to a push.
-        """
-        cfg = self._cfg
-        if self._backend != "jnp":
-            # the probes time the jnp reference stages; the pallas backend
-            # fuses gather+bundle+transpose+counters into one kernel, so
-            # per-stage shares measured here would describe a datapath the
-            # fleet never runs
-            raise ValueError(
-                "stage_probes breaks the step into the jnp reference "
-                f"stages; this fleet runs backend={self._backend!r} — "
-                "benchmark a backend='jnp' fleet")
-        batch = np.asarray(batch, np.uint8)
-        t = batch.shape[1]
-        if not 0 < t <= self._buckets[-1]:
-            raise ValueError(
-                f"stage_probes needs one round, 0 < t <= {self._buckets[-1]}")
-        sl, dev = self._tile_slices[0], self._tile_devs[0]
-        tile_s = sl.stop - sl.start
-        tables, owner = self._tables_t[0], self._param_owner_t[0]
-        thresholds = self._thresholds_t[0]
-        # SNAPSHOT the class rows: the live state leaf is donated by the
-        # next real push, which would delete the buffer under the probe
-        # (callers interleave probe timings with reference pushes)
-        class_rows = jnp.array(self._state_t[0].class_rows)
-        tile_batch = np.zeros((tile_s, t, cfg.channels), np.uint8)
-        tile_batch[:min(tile_s, self._n)] = batch[sl.start:
-                                                  min(sl.stop, self._n)]
-        chunk_d = self._put_tile(tile_batch, ("batch", None, None), dev)
-        filled = self._put_tile(np.zeros(tile_s, np.int32), ("batch",), dev)
-        lengths = self._put_tile(np.full(tile_s, t, np.int32),
-                                 ("batch",), dev)
-
-        # cfg rides in the closure (a static, like the step's partial) —
-        # operands stay explicit jit arguments so nothing constant-folds
-        if self._masked:
-            f_spatial = jax.jit(
-                lambda t_, o, c, m: dispatch.owner_spatial_codes(
-                    t_, o, c, cfg, m))
-            spatial_args = (tables, owner, chunk_d, self._cmask_t[0])
-        else:
-            f_spatial = jax.jit(
-                lambda t_, o, c: dispatch.owner_spatial_codes(t_, o, c, cfg))
-            spatial_args = (tables, owner, chunk_d)
-        words = jax.block_until_ready(f_spatial(*spatial_args))
-        f_temporal = jax.jit(
-            lambda w, f, l: fleet_ops.fleet_counts(w, f, l, cfg))
-        seg = jax.block_until_ready(f_temporal(words, filled, lengths))
-
-        def _am(seg, thr, cls):
-            if cfg.variant == "dense":
-                frames = hv.majority_pack(seg[:, :-1], cfg.window, cfg.dim)
-            else:
-                frames = hv.threshold_pack(seg[:, :-1], thr[:, None, None])
-            return dispatch.owner_am_scores(frames, cls[:, None], cfg)
-        f_am = jax.jit(_am)
-        jax.block_until_ready(f_am(seg, thresholds, class_rows))
-
-        t_bucket = self._bucket_for(t)
-
-        def run_ingest():  # host side of one round: ring writes + puts
-            for k, (tsl, d) in enumerate(zip(self._tile_slices,
-                                             self._tile_devs)):
-                stage = self._stage_buf(k, 0, t_bucket)
-                hi = min(tsl.stop, self._n)
-                if hi > tsl.start:
-                    stage[:hi - tsl.start, :t] = batch[tsl.start:hi]
-                jax.block_until_ready(self._put_tile(
-                    stage, ("batch", None, None), d))
-        run_ingest()
-
-        n_tiles = self.n_tiles
-        return {
-            "ingest": (run_ingest, 1),
-            "spatial": (lambda: jax.block_until_ready(
-                f_spatial(*spatial_args)), n_tiles),
-            "temporal": (lambda: jax.block_until_ready(
-                f_temporal(words, filled, lengths)), n_tiles),
-            "am": (lambda: jax.block_until_ready(
-                f_am(seg, thresholds, class_rows)), n_tiles),
-        }
 
     # -- online adaptation ----------------------------------------------------
 

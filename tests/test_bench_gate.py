@@ -21,12 +21,6 @@ import pytest
 from benchmarks import check_fleet_regression as gate
 from benchmarks import check_reliability_gate as rel_gate
 
-STAGE_ROWS = [
-    {"name": "fleet.S8.stage_spatial", "derived": "share=20.0% of push"},
-    {"name": "fleet.S8.stage_temporal", "derived": "share=30.0% of push"},
-]
-
-
 def _write(tmp_path, fname, rows, status="ok"):
     path = tmp_path / fname
     path.write_text(json.dumps(
@@ -46,13 +40,13 @@ def reference(tmp_path):
 
 def test_gate_passes_within_tolerance(tmp_path, reference):
     fresh = _write(tmp_path, "fresh.json",
-                   [_speedup("fleet.S8.speedup", 3.5)] + STAGE_ROWS)
+                   [_speedup("fleet.S8.speedup", 3.5)])
     assert gate.main([fresh, reference, "--tolerance", "0.25"]) == 0
 
 
 def test_gate_fails_on_regression(tmp_path, reference):
     fresh = _write(tmp_path, "fresh.json",
-                   [_speedup("fleet.S8.speedup", 1.0)] + STAGE_ROWS)
+                   [_speedup("fleet.S8.speedup", 1.0)])
     assert gate.main([fresh, reference, "--tolerance", "0.25"]) == 1
 
 
@@ -63,7 +57,7 @@ def test_unknown_row_family_warns_not_crashes(tmp_path, reference, capsys):
         _speedup("fleet.S8.speedup", 4.0),
         _speedup("fleet.newfamily.speedup", 9.0),
         {"name": "fleet.weird.speedup", "derived": "not a ratio at all"},
-    ] + STAGE_ROWS)
+    ])
     assert gate.main([fresh, reference]) == 0
     err = capsys.readouterr().err
     assert "fleet.newfamily.speedup" in err and "skipping" in err
@@ -72,21 +66,21 @@ def test_unknown_row_family_warns_not_crashes(tmp_path, reference, capsys):
 
 def test_known_row_missing_fails(tmp_path, reference):
     fresh = _write(tmp_path, "fresh.json",
-                   [_speedup("fleet.other.speedup", 4.0)] + STAGE_ROWS)
+                   [_speedup("fleet.other.speedup", 4.0)])
     assert gate.main([fresh, reference]) == 1
 
 
 def test_known_row_unparseable_fails(tmp_path, reference):
     fresh = _write(tmp_path, "fresh.json", [
         {"name": "fleet.S8.speedup", "derived": "garbage"},
-    ] + STAGE_ROWS)
+    ])
     assert gate.main([fresh, reference]) == 1
 
 
 def test_empty_reference_fails(tmp_path):
     ref = _write(tmp_path, "ref.json", [])
     fresh = _write(tmp_path, "fresh.json",
-                   [_speedup("fleet.S8.speedup", 4.0)] + STAGE_ROWS)
+                   [_speedup("fleet.S8.speedup", 4.0)])
     assert gate.main([fresh, ref]) == 1
 
 
@@ -94,26 +88,9 @@ def test_reference_stays_strict(tmp_path):
     ref = _write(tmp_path, "ref.json",
                  [{"name": "fleet.S8.speedup", "derived": "corrupt"}])
     fresh = _write(tmp_path, "fresh.json",
-                   [_speedup("fleet.S8.speedup", 4.0)] + STAGE_ROWS)
+                   [_speedup("fleet.S8.speedup", 4.0)])
     with pytest.raises(SystemExit):
         gate.main([fresh, ref])
-
-
-def test_spatial_share_cap_still_gates(tmp_path, reference, capsys):
-    fresh = _write(tmp_path, "fresh.json", [
-        _speedup("fleet.S8.speedup", 4.0),
-        {"name": "fleet.S8.stage_spatial", "derived": "share=80.0% of push"},
-        {"name": "fleet.S8.stage_ingest", "derived": "mangled"},
-    ])
-    assert gate.main([fresh, reference, "--max-spatial-share", "0.5"]) == 1
-    err = capsys.readouterr().err
-    assert "fleet.S8.stage_ingest" in err  # mangled stage row only warns
-
-
-def test_missing_spatial_breakdown_fails(tmp_path, reference):
-    fresh = _write(tmp_path, "fresh.json",
-                   [_speedup("fleet.S8.speedup", 4.0)])
-    assert gate.main([fresh, reference]) == 1
 
 
 # -- cold-start gating (--coldstart-fresh / --coldstart-reference) ----------
@@ -127,7 +104,7 @@ COLD_STATUS_ROWS = [
 @pytest.fixture
 def fleet_fresh(tmp_path):
     return _write(tmp_path, "fleet_fresh.json",
-                  [_speedup("fleet.S8.speedup", 4.0)] + STAGE_ROWS)
+                  [_speedup("fleet.S8.speedup", 4.0)])
 
 
 @pytest.fixture

@@ -253,23 +253,136 @@ def test_staging_ring_double_buffer_discipline():
         assert {(0, WINDOW), (1, WINDOW)} <= set(per_tile)
 
 
-def test_stage_probes_stages_and_backend_guard():
-    """stage_probes exposes the four stage callables for a jnp fleet (the
-    bench + CI spatial-share gate depend on them) and refuses a pallas
-    fleet, whose fused kernel has no separable stages to time."""
+def _fleet_spans(tmp_path, fn) -> list[tuple]:
+    """Run ``fn`` under the profiler; the ``fleet.*`` spans it recorded as
+    (name, start_ns, end_ns, args), outer spans before inner ones."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    data = jax.profiler.ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb"))))
+    spans = [(e.name, int(e.start_ns), int(e.end_ns), dict(e.stats))
+             for p in data.planes for line in p.lines for e in line.events
+             if e.name.startswith("fleet.")]
+    return sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+@pytest.mark.parametrize("entry", ["push_raw", "push_codes_raw"])
+def test_push_and_collect_spans_nest_in_order(tmp_path, entry):
+    """Two tiles, two rounds: the push span holds stage, h2d and dispatch
+    per tile per round, in that order; the collection holds d2h and decode
+    per tile per round.  The bytes args are the arrays' nbytes and the
+    counters their sums."""
+    pipe = _trained("sparse_compim", seed=3)
+    fleet = StreamingFleet({"p": pipe}, ["p"] * 7, buckets=(WINDOW,),
+                           tile=4)
+    rng = np.random.default_rng(4)
+    batch = np.stack([_chunk(rng, 2 * WINDOW) for _ in range(7)])
+    got = {}
+
+    def push_and_collect():
+        rounds = (fleet.push_raw(list(batch)) if entry == "push_raw"
+                  else fleet.push_codes_raw(batch))
+        got["rounds"] = rounds
+        got["decisions"] = fleet.collect_decisions(rounds)
+
+    spans = _fleet_spans(tmp_path, push_and_collect)
+    per_tile = ["fleet.stage", "fleet.h2d", "fleet.dispatch"]
+    assert [n for n, *_ in spans] == (
+        ["fleet.push"] + per_tile * 4
+        + ["fleet.collect"] + ["fleet.d2h", "fleet.decode"] * 4)
+    push, collect = spans[0], spans[13]
+    assert push[3] == {"rounds": 2}
+    for name, s, e, args in spans[1:13]:
+        assert push[1] <= s <= e <= push[2]
+    for name, s, e, args in spans[14:]:
+        assert collect[1] <= s <= e <= collect[2]
+    assert [a["tile"] for n, _, _, a in spans if n == "fleet.dispatch"] \
+        == [0, 1, 0, 1]
+    assert {(a["bucket"], a["path"]) for n, _, _, a in spans
+            if n == "fleet.dispatch"} == {(WINDOW, "jit")}
+    h2d = [a["bytes"] for n, _, _, a in spans if n == "fleet.h2d"]
+    assert h2d == [4 * WINDOW * CHANNELS + 4 * 4] * 4  # codes + lengths
+    d2h = [a["bytes"] for n, _, _, a in spans if n == "fleet.d2h"]
+    assert d2h == [fo.frames.nbytes + fo.scores.nbytes
+                   for r in got["rounds"] for fo in r.tiles]
+    decode = [a["decisions"] for n, _, _, a in spans if n == "fleet.decode"]
+    assert sum(decode) == sum(map(len, got["decisions"])) == 14
+    assert fleet.counters == {"h2d_bytes": sum(h2d), "d2h_bytes": sum(d2h),
+                              "stage_waits": 0, "jit_steps": 4}
+
+
+def test_counters_are_a_snapshot_and_count_without_a_profiler():
+    """``counters`` hands out a copy; pushes without a profiler still count
+    what they move."""
     pipe = _trained("sparse_compim", seed=3)
     fleet = StreamingFleet({"p": pipe}, ["p"] * 2, buckets=(WINDOW,))
-    rng = np.random.default_rng(2)
-    batch = np.stack([_chunk(rng, WINDOW)] * 2)
-    probes = fleet.stage_probes(batch)
-    assert set(probes) == {"ingest", "spatial", "temporal", "am"}
-    for fn, scale in probes.values():
-        assert scale >= 1
-        fn()  # runs and blocks without error
-    pallas = StreamingFleet({"p": pipe}, ["p"] * 2, buckets=(WINDOW,),
-                            backend="pallas")
-    with pytest.raises(ValueError, match="backend='jnp'"):
-        pallas.stage_probes(batch)
+    snap = fleet.counters
+    snap["h2d_bytes"] = 99
+    assert fleet.counters["h2d_bytes"] == 0
+    rng = np.random.default_rng(1)
+    fleet.push([_chunk(rng, WINDOW), _chunk(rng, WINDOW)])
+    c = fleet.counters
+    assert c["h2d_bytes"] == 2 * WINDOW * CHANNELS + 2 * 4
+    assert c["d2h_bytes"] > 0 and c["jit_steps"] == 1
+
+
+def test_stage_waits_counts_a_slot_whose_reader_is_in_flight():
+    """A staging slot rewritten while the step that read it still runs
+    blocks the push on that step, and counts; a finished reader does not.
+    Decisions stay bit-exact either way."""
+    pipe = _trained("sparse_compim", seed=3)
+    fleet = StreamingFleet({"p": pipe}, ["p"] * 2, buckets=(WINDOW,))
+    session = SeizureSession(pipe)
+    rng = np.random.default_rng(6)
+    for _ in range(3):  # both slots reused, every reader long finished
+        chunk = _chunk(rng, WINDOW)
+        _assert_decisions_equal(fleet.push([chunk, chunk])[0],
+                                session.push(chunk))
+    assert fleet.counters["stage_waits"] == 0
+    slow = jax.jit(lambda x: jax.lax.fori_loop(
+        0, 200, lambda i, a: jnp.tanh(a @ a), x))
+    x = jnp.full((512, 512), 1e-3, jnp.float32)
+    slow(x).block_until_ready()  # compiled; the next call runs async
+    reader = slow(x)
+    assert not reader.is_ready()
+    fleet._stage_busy[0][(fleet._stage_phase & 1, WINDOW)] = reader
+    chunk = _chunk(rng, WINDOW)
+    _assert_decisions_equal(fleet.push([chunk, chunk])[0],
+                            session.push(chunk))
+    assert reader.is_ready()
+    assert fleet.counters["stage_waits"] == 1
+
+
+def test_jit_steps_counts_steps_a_warmed_executable_refused():
+    """Warmed executables serve steps without the jit; one that refuses its
+    operands (here: the other bucket's) sends the step, and every later one
+    of that bucket, through the jitted callable."""
+    pipe = _trained("sparse_compim", seed=2)
+    fleet = StreamingFleet({"p": pipe}, ["p"] * 2,
+                           buckets=(WINDOW, 2 * WINDOW))
+    fleet.warmup()
+    rng = np.random.default_rng(3)
+    fleet.push([_chunk(rng, 2 * WINDOW)] * 2)
+    assert fleet.counters["jit_steps"] == 0
+    small, big = sorted(fleet._exec, key=lambda key: key[2])
+    fleet._exec[small] = fleet._exec[big]
+    fleet.push([_chunk(rng, WINDOW)] * 2)
+    fleet.push([_chunk(rng, WINDOW)] * 2)
+    fleet.push([_chunk(rng, 2 * WINDOW)] * 2)
+    assert fleet.counters["jit_steps"] == 2
+
+
+def test_step_and_adapt_modules_are_named():
+    """The step's and adapt's XLA modules carry their names, so a profile
+    shows ``jit_fleet_step`` and ``jit_fleet_adapt``."""
+    pipe = _trained("sparse_compim", seed=3)
+    fleet = StreamingFleet({"p": pipe}, ["p"] * 2, buckets=(WINDOW,))
+    names = {e.fn.lower(*e.args).as_text().split("module @", 1)[1]
+             .split(" ", 1)[0] for e in fleet.aot_entries()}
+    assert names == {"jit_fleet_step", "jit_fleet_adapt"}
 
 
 def test_push_codes_validation():

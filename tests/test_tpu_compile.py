@@ -13,6 +13,7 @@ worker imports this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,7 @@ from repro.kernels.lbp.kernel import lbp_pallas
 from repro.launch.mesh import make_mesh
 from repro.runtime import sharding as shd
 from repro.runtime.aot import persistent_cache_off
-from repro.serve.fleet import FleetState, _fleet_step
+from repro.serve.fleet import FleetState, _fleet_step, _named
 
 CFG = HDCConfig()                      # the paper's geometry
 S, PATIENTS, T = 1024, 16, CFG.window  # sessions, table rows, chunk cycles
@@ -122,6 +123,24 @@ def test_fleet_step_compiles(one_chip, monkeypatch, backend, masked):
     text = jax.jit(step, donate_argnums=(0,)).lower(
         *_step_args(one_chip, masked=masked)).compile().as_text()
     assert ("tpu_custom_call" in text) == (backend == "pallas")
+
+
+def test_fleet_step_names_its_kernel_and_phases(one_chip, monkeypatch):
+    """The step as StreamingFleet names it compiles to module
+    ``jit_fleet_step`` whose Mosaic custom call is named
+    ``hdc_fleet_counts``, with the step's phases as named scopes in the ops'
+    metadata: the names a profile of the chip shows."""
+    monkeypatch.setattr(fleet_ops, "use_interpret", lambda: False)
+    step = _named("fleet_step", functools.partial(
+        _fleet_step, cfg=CFG, ctx=shd.make_ctx(None), use_kernel=True))
+    text = jax.jit(step, donate_argnums=(0,)).lower(
+        *_step_args(one_chip, masked=False)).compile().as_text()
+    assert text.startswith("HloModule jit_fleet_step,")
+    assert re.search(r"%hdc_fleet_counts(\.\d+)? = [^\n]* custom-call\("
+                     r"[^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    for scope in ("spatial_temporal", "pack_codes", "threshold_pack",
+                  "am_scores", "state_update"):
+        assert f"/{scope}/" in text, scope
 
 
 def test_sharded_fleet_step_compiles(topo):
