@@ -6,10 +6,11 @@ Two paths, both bit-exact with the per-session reference datapaths:
   packed spatial HVs and needs NO masks (slot membership is contiguous, so
   counts are prefix-count differences at slot boundaries).
 * ``fleet_counts_fused`` — the Pallas kernel (kernel.py): takes RAW uint8
-  codes plus the stacked pre-bound codebook bank and fuses the table gather
-  (bind), spatial bundling, bit transpose and masked-popcount temporal
-  accumulation in VMEM, driven by device-computed time-packed emission
-  masks (ref.emission_masks).  Nothing per-cycle wider than the codes
+  codes plus the stacked pre-bound codebook bank and runs the table gather
+  (bind) and spatial bundling as one-hot codes x the patient's unpacked
+  bit table on the MXU, then the temporal accumulation as a second
+  product against the device-computed emission masks
+  (ref.emission_masks).  Nothing per-cycle wider than the codes
   themselves ever crosses HBM.
 
 ``spatial_mode`` maps an HDCConfig onto the kernel's spatial-bundle variant
@@ -53,22 +54,22 @@ def fleet_counts_fused(tables: jax.Array, owner: jax.Array,
     ``tables`` is the stacked (P, C, K, W) pre-bound codebook bank and
     ``owner`` each session's row into it (scalar-prefetched by the kernel's
     table BlockSpec).  Pads the cycle axis to a 32 multiple (padded cycles
-    gather row 0 but are masked off by the emission schedule) and runs the
-    fused kernel; interpret mode off-TPU.
+    are masked off by the emission schedule) and runs the fused kernel;
+    interpret mode off-TPU.
 
     ``tables_xor`` (same shape as ``tables``) is the reliability
     subsystem's fault-injection hook (repro.reliability.faults): an
     effective bit-flip mask XORed into the codebook bank HERE, adjacent to
-    the kernel launch, so the VMEM-resident table BlockSpec prefetches the
-    FAULTED bank — the corruption rides the same operand path as the clean
-    bank and the kernel body is untouched.  ``None`` (the default) skips
-    the XOR entirely.
+    the kernel launch, so the table BlockSpec fetches (and the kernel
+    unpacks) the FAULTED bank — the corruption rides the same operand path
+    as the clean bank and the kernel body is untouched.  ``None`` (the
+    default) skips the XOR entirely.
 
     ``chan_mask`` (S, C) uint8/uint32, the channel-fault tolerance hook
     (repro.reliability.channels): quarantined channels drop out of the
     in-kernel spatial bundle with renormalized count denominators, exactly
     like dispatch.owner_spatial_codes' masked path.  ``None`` (the
-    default) keeps the kernel's operand list and body untouched.
+    default) leaves the mask operand out of the kernel.
     """
     s, t, c = codes.shape
     if tables_xor is not None:

@@ -35,9 +35,9 @@ threshold/majority-pack + AM search scores all K frame slots of all
 sessions together.  ``lengths`` masks the padding — sessions push chunks
 of ANY length, including 0 — and chunk lengths are bucketed/padded to a
 fixed set so steady streams compile once per bucket.  With
-``backend="pallas"`` the table gather + spatial bundle + bit transpose +
-masked-popcount accumulate run as ONE fused VMEM kernel with the CompIM
-table bank resident in VMEM (codes in, per-slot counts out).
+``backend="pallas"`` the table gather + spatial bundle + temporal
+accumulate run as ONE fused kernel: one-hot codes times the patient's
+unpacked bit table on the MXU (codes in, per-slot counts out).
 
 The step is memory-bound, so the fleet partitions sessions into TILES
 (``derive_tile``: sized from the device's reported memory geometry, the
@@ -78,6 +78,7 @@ the sessions-per-second win over the looped baseline.
 from __future__ import annotations
 
 import functools
+import gc
 import hashlib
 import json
 import os
@@ -269,7 +270,7 @@ def _fleet_step(
     the spatial stage is a fused gather+bind+bundle out of the pre-bound
     codebook bank (``dispatch.owner_spatial_codes``, never materializing
     the (S, T, C, W) bound expansion), temporal counts are popcount prefix
-    sums at frame-slot boundaries, or ONE fused VMEM kernel does all of it
+    sums at frame-slot boundaries, or ONE fused MXU kernel does all of it
     when ``use_kernel``.  Frames score against ``state.class_rows``
     (refreshed by ``adapt``), and the step records each emitting session's
     last frame HV + scores — the operands a later ``adapt`` call consumes,
@@ -317,7 +318,7 @@ def _fleet_step(
     with jax.named_scope("spatial_temporal"):
         if use_kernel:
             # fused kernel: codes in, slot counts out — the table gather,
-            # spatial bundle, bit transpose and masked popcount stay in VMEM
+            # spatial bundle and temporal counts stay on the core
             seg = fleet_ops.fleet_counts_fused(tables, owner, chunk,
                                                state.filled, lengths, cfg,
                                                tables_xor=tables_xor,
@@ -453,6 +454,13 @@ def _push_span(push):
     return spanned
 
 
+def _freeze_heap() -> None:
+    """Collect once, then move every object still alive out of the cyclic
+    collector's view, so its later passes walk only what serving makes."""
+    gc.collect()
+    gc.freeze()
+
+
 # sharding axes of what ``_rounds`` puts per tile: codes, lengths, fault seed
 _H2D_AXES = (("batch", None, None), ("batch",), ())
 
@@ -487,8 +495,8 @@ class StreamingFleet:
     packing at all.
 
     ``backend`` selects the device datapath ("jnp" = pure XLA code-domain
-    gather + bit-plane path, "pallas" = fused VMEM kernel with the CompIM
-    table bank resident on chip; both bit-exact); defaults to the bank's
+    gather + bit-plane path, "pallas" = fused MXU kernel over the CompIM
+    table bank; both bit-exact); defaults to the bank's
     pipeline backend.
 
     ``adapt(labels)`` personalizes AMs in place: one jitted gated update for
@@ -1047,11 +1055,18 @@ class StreamingFleet:
         see above).  Returns ``{"loaded", "compiled", "skipped"}`` counts.
         Under a mesh the step is a sharded SPMD program the artifact format
         does not carry; warmup is a no-op there (plain JIT, one warning).
+
+        Warm-up ends by freezing the heap built so far (``gc.freeze``, after
+        one full collection): the imported modules, the executables and the
+        bank live as long as the fleet, and the cyclic collector's full
+        passes, which serving's per-push decision objects trigger every few
+        dozen pushes, would otherwise walk all of it under a push.
         """
         stats = {"loaded": 0, "compiled": 0, "skipped": 0}
         if self._ctx.mesh is not None:
             warnings.warn("StreamingFleet.warmup: mesh-sharded fleets "
                           "fall back to JIT (no AOT path)", stacklevel=2)
+            _freeze_heap()
             return stats
         default_dev = jax.local_devices()[0]
         for k, (sl, dev) in enumerate(zip(self._tile_slices,
@@ -1084,6 +1099,7 @@ class StreamingFleet:
                     compiled = self._adapt_step.lower(
                         *self._adapt_avals(k, dev=dev)).compile()
                 self._adapt_exec[akey] = compiled
+        _freeze_heap()
         return stats
 
     @classmethod

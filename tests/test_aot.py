@@ -2,6 +2,7 @@
 artifact roundtrips, warmed-fleet and prewarm-engine bit-exactness with the
 JIT path, checkpoint-recorded artifacts, and stale-artifact JIT fallback."""
 
+import gc
 import json
 import os
 
@@ -96,6 +97,23 @@ def test_warmup_precompiles_and_matches_jit(no_recompiles):
     with no_recompiles():
         got = warm.push(chunks)
     assert _decisions(got) == want
+
+
+def test_warmup_freezes_the_heap_it_built():
+    """Warm-up moves what it built (the executables among it) out of the
+    cyclic collector's view, so a full collection while serving walks only
+    what serving made; objects made afterwards stay collectable."""
+    pipe = _trained("sparse_compim", seed=0)
+    fleet = StreamingFleet({"p": pipe}, ["p"] * 4, buckets=(WINDOW,))
+    gc.unfreeze()
+    try:
+        fleet.warmup()
+        assert gc.get_freeze_count() > 0
+        assert id(fleet._exec) not in {id(o) for o in gc.get_objects()}
+        later = {"made": ["after warm-up"]}
+        assert id(later) in {id(o) for o in gc.get_objects()}
+    finally:
+        gc.unfreeze()
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])

@@ -231,40 +231,53 @@ def test_fleet_counts_ref_matches_einsum_oracle(t_pad, window):
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("mode,threshold", [("or", 0), ("thin", 2),
+@pytest.mark.parametrize("patients", ["one", "per_session"])
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("mode,threshold", [("or", 0), ("thin", 3),
                                             ("majority", 0)])
-def test_fleet_kernel_vs_ref(mode, threshold):
-    """The fused code-domain kernel (VMEM table gather + spatial bundle +
-    bit transpose + masked popcount) must match the jnp bit-plane path for
-    every spatial-bundle mode, with per-session owner-gathered tables."""
+@pytest.mark.parametrize("t", [32, 64, 128, 256])
+def test_fleet_kernel_vs_ref(t, mode, threshold, masked, patients):
+    """The fused code-domain kernel (bit-table unpack + one-hot matrix
+    product + threshold + masked temporal product, sessions walked in owner
+    order) must equal the jnp bit-plane path exactly: every bucket, every
+    spatial-bundle mode, masked and unmasked, one patient or one per
+    session in unsorted order, out-of-alphabet codes, partial ``filled``
+    and ``lengths``."""
     from repro.kernels.hdc_fleet.kernel import fleet_counts_pallas
     from repro.kernels.hdc_fleet.ref import emission_masks, fleet_counts_ref
-    rng = np.random.default_rng(3)
-    s, t, c, w, window, p, k = 5, 64, 6, 2, 32, 3, 8
+    rng = np.random.default_rng(t + 7 * masked)
+    s, c, w, window, k = 5, 6, 2, 32, 8
+    p = 1 if patients == "one" else s
     dim = w * 32
     tables = rng.integers(0, 2**32, (p, c, k, w), dtype=np.uint32)
-    owner = rng.integers(0, p, s).astype(np.int32)
-    codes = rng.integers(0, k, (s, t, c), dtype=np.uint8)
+    owner = (rng.permutation(s) if p == s else np.zeros(s)).astype(np.int32)
+    codes = rng.integers(0, k + 4, (s, t, c), dtype=np.uint8)  # some OOB
     filled = jnp.asarray(rng.integers(0, window, s), jnp.int32)
     lengths = jnp.asarray(rng.integers(0, t + 1, s), jnp.int32)
-    # gather + spatial bundle in numpy -> per-cycle words for the ref path
-    bound = tables[owner[:, None, None],
-                   np.arange(c)[None, None, :], codes]     # (s, t, c, w)
+    live = (rng.random((s, c)) > 0.3 if masked
+            else np.ones((s, c), bool))
+    # gather + spatial bundle in numpy -> per-cycle words for the ref path;
+    # an out-of-alphabet code clamps within its channel's rows
+    bound = tables[owner[:, None, None], np.arange(c)[None, None, :],
+                   np.minimum(codes, k - 1)]               # (s, t, c, w)
     bits = ((bound[..., None] >> np.arange(32, dtype=np.uint32)) & 1)
-    bits = bits.reshape(s, t, c, dim)
+    bits = bits.reshape(s, t, c, dim) * live[:, None, :, None]
+    n = live.sum(axis=1)[:, None, None]                    # live channels
     if mode == "or":
         spat = bits.any(axis=2)
     elif mode == "thin":
-        spat = bits.sum(axis=2) >= threshold
+        spat = bits.sum(axis=2) >= np.maximum(1, -(-threshold * n // c))
     else:
-        spat = bits.sum(axis=2) * 2 > c
+        spat = bits.sum(axis=2) * 2 > n
     words = hv.np_pack_bits(spat.astype(np.uint8))
     ref = np.asarray(fleet_counts_ref(
         jnp.asarray(words), filled, lengths, window=window, dim=dim))
     tm = emission_masks(filled, lengths, t_pad=t, window=window)
     got = np.asarray(fleet_counts_pallas(
         jnp.asarray(tables), jnp.asarray(owner), jnp.asarray(codes), tm,
-        mode=mode, dim=dim, threshold=threshold, interpret=True))
+        mode=mode, dim=dim, threshold=threshold,
+        chan_mask=jnp.asarray(live, jnp.uint8) if masked else None,
+        interpret=True))
     np.testing.assert_array_equal(got, ref)
 
 

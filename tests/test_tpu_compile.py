@@ -72,11 +72,14 @@ def _compile(fn, *args):
 
 @pytest.mark.parametrize("masked", [False, True])
 @pytest.mark.parametrize("mode", ["or", "thin", "majority"])
-def test_fleet_kernel_compiles(one_chip, mode, masked):
+@pytest.mark.parametrize("t", [32, T])
+def test_fleet_kernel_compiles(one_chip, t, mode, masked):
+    """The shortest and the longest default bucket: below 128 cycles the
+    MXU pads the chunk's columns, at 256 it does not."""
     args = [_sds((PATIENTS, C, K, W), jnp.uint32, one_chip),
             _sds((S,), jnp.int32, one_chip),
-            _sds((S, T, C), jnp.uint8, one_chip),
-            _sds((S, 2, T // 32), jnp.uint32, one_chip)]
+            _sds((S, t, C), jnp.uint8, one_chip),
+            _sds((S, 2, t // 32), jnp.uint32, one_chip)]
     if masked:
         args.append(_sds((S, C), jnp.uint32, one_chip))
 
@@ -85,7 +88,9 @@ def test_fleet_kernel_compiles(one_chip, mode, masked):
                                    threshold=2, chan_mask=chan_mask,
                                    interpret=False)
 
-    assert "tpu_custom_call" in _compile(f, *args)
+    assert re.search(r"%hdc_fleet_counts(\.\d+)? = [^\n]* custom-call\("
+                     r"[^\n]*custom_call_target=\"tpu_custom_call\"",
+                     _compile(f, *args))
 
 
 def _step_args(sharding, *, masked: bool, batch=None):
@@ -138,7 +143,7 @@ def test_fleet_step_names_its_kernel_and_phases(one_chip, monkeypatch):
     assert text.startswith("HloModule jit_fleet_step,")
     assert re.search(r"%hdc_fleet_counts(\.\d+)? = [^\n]* custom-call\("
                      r"[^\n]*custom_call_target=\"tpu_custom_call\"", text)
-    for scope in ("spatial_temporal", "pack_codes", "threshold_pack",
+    for scope in ("spatial_temporal", "transpose_codes", "threshold_pack",
                   "am_scores", "state_update"):
         assert f"/{scope}/" in text, scope
 
